@@ -60,19 +60,19 @@ ITAMAX_LOGIT_SCALE = math.log(2.0) / (1 << ITAMAX_B)  # ~0.021661
 # U1.8 LUT used by the paper-faithful rowwise path (matches ITA's internal
 # precision; 256 == 2^8 represents 1.0).
 EXP_LUT_BITS = 8
-_EXP_LUT_NP = np.round((1 << EXP_LUT_BITS) * 2.0 ** (-np.arange(32) / 32.0)).astype(np.int32)
+EXP_LUT = np.round((1 << EXP_LUT_BITS) * 2.0 ** (-np.arange(32) / 32.0)).astype(np.int32)
 
 # U0.7 LUT used by the flash path so un-normalized exponentials fit int8
 # and can feed the MXU directly (127 represents ~1.0).
 EXP_LUT7_BITS = 7
-_EXP_LUT7_NP = np.minimum(
+EXP_LUT7 = np.minimum(
     np.round((1 << EXP_LUT7_BITS) * 2.0 ** (-np.arange(32) / 32.0)), 127
 ).astype(np.int32)
 
 # U1.10 LUT used to renormalize the flash-path running sums on a max
 # update (higher precision than the value LUT; 1024 represents 1.0).
 RENORM_LUT_BITS = 10
-_RENORM_LUT_NP = np.round(
+RENORM_LUT = np.round(
     (1 << RENORM_LUT_BITS) * 2.0 ** (-np.arange(32) / 32.0)
 ).astype(np.int32)
 
@@ -88,44 +88,56 @@ A_BITS = 7
 A_SCALE = 2.0 ** (-A_BITS)
 
 
-def exp_lut() -> jnp.ndarray:
-    return jnp.asarray(_EXP_LUT_NP, jnp.int32)
+def lut_lookup(table: np.ndarray, r: jnp.ndarray) -> jnp.ndarray:
+    """``table[r]`` for a 32-entry int32 table and int32 ``r`` in [0, 32).
+
+    A binary tree of selects on the five bits of ``r`` with the entries
+    as scalar constants: the TPU kernel compiler has no 1-D gather, and
+    Pallas kernels cannot capture array constants.  The XLA reference
+    paths call the same function, so kernels and references agree bit
+    for bit.
+    """
+    assert table.shape == (1 << ITAMAX_B,), table.shape
+    vals = [np.int32(v) for v in table]
+    for bit in range(ITAMAX_B):
+        take_hi = ((r >> bit) & 1) == 1
+        vals = [jnp.where(take_hi, hi, lo) for lo, hi in zip(vals[0::2], vals[1::2])]
+    return jnp.asarray(vals[0], jnp.int32)
 
 
-def exp_lut7() -> jnp.ndarray:
-    return jnp.asarray(_EXP_LUT7_NP, jnp.int32)
+def lut_gather(table: np.ndarray, r: jnp.ndarray) -> jnp.ndarray:
+    """``table[r]`` as an XLA gather, for code that never runs inside a
+    Pallas kernel.  Once XLA fuses :func:`lut_lookup`'s select tree into
+    the decoder MLP, the TPU compiler spends minutes on the fusion (about
+    220 s for one olmo-1b layer at batch 4, against 45 s with the gather)."""
+    return jnp.asarray(table, jnp.int32)[r]
 
 
-def renorm_lut() -> jnp.ndarray:
-    return jnp.asarray(_RENORM_LUT_NP, jnp.int32)
-
-
-def _exp2_int(t: jnp.ndarray, lut: jnp.ndarray, lut_bits: int) -> jnp.ndarray:
+def _exp2_int(t: jnp.ndarray, lut: np.ndarray, lut_bits: int,
+              lookup=lut_lookup) -> jnp.ndarray:
     """``round(2^lut_bits * 2^(-t / 2^B))`` for non-negative int32 ``t``.
 
     The integer-part shift uses round-half-up (not floor): small
     exponentials would otherwise be systematically under-weighted and the
-    attention rows would sum to < 1.
+    attention rows would sum to < 1.  ``lookup`` reads the table; both
+    lookups return exactly ``lut[r]``.
     """
     t = jnp.asarray(t, jnp.int32)
     q = jnp.minimum(t >> ITAMAX_B, 31)
     r = t & _FRAC_MASK
     bias = jnp.where(q > 0, jnp.int32(1) << jnp.maximum(q - 1, 0), 0)
-    return (lut[r] + bias) >> q
+    return (lookup(lut, r) + bias) >> q
 
 
 def itamax_rowwise(
     logits: jnp.ndarray,
     mask: jnp.ndarray | None = None,
-    lut: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """Paper-faithful ITAMax over the last axis of int8 ``logits``.
 
     Returns int8 attention weights ``A`` in [0, 127] with scale ``2^-7``.
     ``mask`` (bool, True = keep) excludes positions from both max and sum.
     Row length should be <= 2^15 so that the denominator fits INV_BITS.
-    ``lut`` lets Pallas kernels pass the exp table as an operand (Pallas
-    forbids closure-captured array constants).
     """
     x = jnp.asarray(logits, jnp.int32)
     neg = jnp.int32(-(1 << 20))
@@ -133,7 +145,7 @@ def itamax_rowwise(
         x = jnp.where(mask, x, neg)
     m = jnp.max(x, axis=-1, keepdims=True)
     t = jnp.clip(m - x, 0, (1 << 20))  # masked positions get huge t -> val 0
-    val = _exp2_int(t, exp_lut() if lut is None else lut, EXP_LUT_BITS)
+    val = _exp2_int(t, EXP_LUT, EXP_LUT_BITS)
     if mask is not None:
         val = jnp.where(mask, val, 0)
     d = jnp.sum(val, axis=-1, keepdims=True)
@@ -194,12 +206,12 @@ def _mul_q10(x: jnp.ndarray, mult: jnp.ndarray) -> jnp.ndarray:
     return b + (c >> RENORM_LUT_BITS)
 
 
-def _renorm_factor_apply(x: jnp.ndarray, delta: jnp.ndarray, rlut: jnp.ndarray) -> jnp.ndarray:
+def _renorm_factor_apply(x: jnp.ndarray, delta: jnp.ndarray) -> jnp.ndarray:
     """Multiply int32 ``x`` by ``2^(-delta / 2^B)`` (delta >= 0, broadcast)."""
     q = jnp.minimum(delta >> ITAMAX_B, 31)
     r = delta & _FRAC_MASK
     x_shifted = rounding_rshift_safe(x, q)
-    return _mul_q10(x_shifted, rlut[r])
+    return _mul_q10(x_shifted, lut_lookup(RENORM_LUT, r))
 
 
 def rounding_rshift_safe(x: jnp.ndarray, shift: jnp.ndarray) -> jnp.ndarray:
@@ -215,14 +227,11 @@ def flash_block_update(
     logits_block: jnp.ndarray,  # int8/int32 [..., bk]
     v_block: jnp.ndarray,  # int8 [bk, out_dim] (or [..., bk, out_dim])
     mask_block: jnp.ndarray | None = None,
-    luts: tuple[jnp.ndarray, jnp.ndarray] | None = None,
 ) -> FlashItamaxState:
     """One DA + fused A@V step over a KV block (pure-jnp oracle form).
 
-    The Pallas kernel implements exactly this computation with MXU dots,
-    passing ``luts = (exp_lut7, renorm_lut)`` as kernel operands.
+    The Pallas kernel runs exactly this computation with MXU dots.
     """
-    lut7, rlut = (exp_lut7(), renorm_lut()) if luts is None else luts
     if mask_block is not None and logits_block.dtype == jnp.int8:
         # Mask in the int8 domain (4x less select traffic than int32).
         # Sound & bit-exact: real logits are >= -128, so a masked -128 can
@@ -236,23 +245,26 @@ def flash_block_update(
     bm = jnp.max(x, axis=-1, keepdims=True)
     new_m = jnp.maximum(state.m, bm)
     delta_old = jnp.clip(new_m - state.m, 0, 1 << 12)
-    d_r = _renorm_factor_apply(state.d, delta_old, rlut)
-    acc_r = _renorm_factor_apply(state.acc, delta_old[..., 0:1], rlut)
+    d_r = _renorm_factor_apply(state.d, delta_old)
+    acc_r = _renorm_factor_apply(state.acc, delta_old[..., 0:1])
 
     t = jnp.clip(new_m - x, 0, 1 << 20)
-    val = _exp2_int(t, lut7, EXP_LUT7_BITS)  # [..., bk] in [0, 127]
+    val = _exp2_int(t, EXP_LUT7, EXP_LUT7_BITS)  # [..., bk] in [0, 127]
     if mask_block is not None:
         val = jnp.where(mask_block, val, 0)
     d_new = d_r + jnp.sum(val, axis=-1, keepdims=True)
 
-    v = jnp.asarray(v_block, jnp.int32)
+    # int8 x int8 -> int32 is the product the MXU takes (the TPU kernel
+    # compiler refuses int32 operands); val <= 127, so the cast is exact
+    val8 = val.astype(jnp.int8)
+    v = jnp.asarray(v_block).astype(jnp.int8)
     if v.ndim == x.ndim:
         # val: [..., q, bk], v: [..., bk, out_dim] with shared leading dims
         contrib = jnp.einsum(
-            "...qk,...kd->...qd", val, v, preferred_element_type=jnp.int32
+            "...qk,...kd->...qd", val8, v, preferred_element_type=jnp.int32
         )
     else:  # v shared across rows: [bk, out_dim]
-        contrib = jnp.einsum("...k,kd->...d", val, v, preferred_element_type=jnp.int32)
+        contrib = jnp.einsum("...k,kd->...d", val8, v, preferred_element_type=jnp.int32)
     acc_new = acc_r + contrib
 
     # Magnitude guard: keep d (and acc, scaled identically so the final

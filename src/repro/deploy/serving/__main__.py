@@ -30,6 +30,7 @@ def main(argv=None):
         add_sanitize_args,
         add_serving_args,
         apply_sanitize_args,
+        enable_compile_cache,
         make_sampling,
         make_scheduler_from_args,
     )
@@ -52,6 +53,7 @@ def main(argv=None):
                   "always plan-backed")
     args = ap.parse_args(argv)
     apply_sanitize_args(args)  # before any engine/allocator exists
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -14,8 +14,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 from repro.core.igelu import IGeluParams, igelu_int
 from repro.quant.qparams import requantize
 
@@ -47,7 +45,7 @@ def igelu_pallas(
         in_specs=[pl.BlockSpec((block_m, block_n), lambda i, j: (i, j))],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int8),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")
         ),
         interpret=interpret,

@@ -2,12 +2,12 @@
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.igelu import make_igelu_params
 from repro.kernels.igelu.kernel import igelu_pallas
+from repro.kernels.interpret import interpret_mode
 from repro.quant.qparams import make_qparams
 
 
@@ -18,10 +18,7 @@ def igelu(
     out_scale: float,
     block_m: int = 256,
     block_n: int = 512,
-    interpret: bool | None = None,
 ) -> jnp.ndarray:
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     *lead, n = x_q.shape
     m = int(np.prod(lead)) if lead else 1
     gelu = make_igelu_params(in_scale)
@@ -33,6 +30,6 @@ def igelu(
         shift=qp.shift,
         block_m=min(block_m, m),
         block_n=min(block_n, n),
-        interpret=interpret,
+        interpret=interpret_mode(),
     )
     return out.reshape(*lead, n)

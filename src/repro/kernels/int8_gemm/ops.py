@@ -2,18 +2,14 @@
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.igelu import make_igelu_params
 from repro.core.quant_linear import ACT_GELU, ACT_IDENTITY
 from repro.kernels.int8_gemm.kernel import int8_gemm_pallas
+from repro.kernels.interpret import interpret_mode
 from repro.quant.qparams import make_qparams, np_quantize_multiplier
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def int8_gemm(
@@ -29,7 +25,6 @@ def int8_gemm(
     block_m: int = 256,
     block_n: int = 256,
     block_k: int = 512,
-    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Quantized linear: int8 in/out, ITA GEMM-mode semantics.
 
@@ -37,8 +32,6 @@ def int8_gemm(
     scales (the kernel accumulates over K in one int32 scratch, which is
     associative in integer arithmetic, so blocking cannot change results).
     """
-    if interpret is None:
-        interpret = _default_interpret()
     *lead, kdim = x_q.shape
     n = w_q.shape[1]
     m = int(np.prod(lead)) if lead else 1
@@ -82,6 +75,6 @@ def int8_gemm(
         gelu=gelu,
         gelu_mult=gelu_mult,
         gelu_shift=gelu_shift,
-        interpret=interpret,
+        interpret=interpret_mode(),
     )
     return out.reshape(*lead, n)

@@ -31,8 +31,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 from repro.core import itamax as im
 from repro.quant.qparams import requantize
 
@@ -41,8 +39,6 @@ def _attn_kernel(
     q_ref,  # (1, bq, D) int8
     k_ref,  # (1, bk, D) int8
     v_ref,  # (1, bk, D) int8
-    lut7_ref,  # (1, 32) int32 exp LUT (7-bit)
-    rlut_ref,  # (1, 32) int32 renorm LUT (10-bit)
     o_ref,  # (1, bq, D) int8
     m_ref,  # VMEM (bq, 1) int32
     d_ref,  # VMEM (bq, 1) int32
@@ -98,9 +94,7 @@ def _attn_kernel(
                 )
                 mask = mask & (kg <= qg)
         state = im.FlashItamaxState(m=m_ref[...], d=d_ref[...], acc=acc_ref[...])
-        new_state = im.flash_block_update(
-            state, logits, v_ref[0], mask, luts=(lut7_ref[0], rlut_ref[0])
-        )
+        new_state = im.flash_block_update(state, logits, v_ref[0], mask)
         m_ref[...] = new_state.m
         d_ref[...] = new_state.d
         acc_ref[...] = new_state.acc
@@ -166,8 +160,6 @@ def ita_attention_pallas(
             pl.BlockSpec((1, block_q, d), lambda h, i, k: (h, i, 0)),
             pl.BlockSpec((1, block_k, d), lambda h, i, k, g=group: (h // g, k, 0)),
             pl.BlockSpec((1, block_k, d), lambda h, i, k, g=group: (h // g, k, 0)),
-            pl.BlockSpec((1, 32), lambda h, i, k: (0, 0)),
-            pl.BlockSpec((1, 32), lambda h, i, k: (0, 0)),
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda h, i, k: (h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), jnp.int8),
@@ -176,8 +168,8 @@ def ita_attention_pallas(
             pltpu.VMEM((block_q, 1), jnp.int32),
             pltpu.VMEM((block_q, d), jnp.int32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(q_q, k_q, v_q, im.exp_lut7()[None, :], im.renorm_lut()[None, :])
+    )(q_q, k_q, v_q)

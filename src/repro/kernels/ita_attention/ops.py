@@ -2,15 +2,11 @@
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.core.attention import MhaQParams
+from repro.kernels.interpret import interpret_mode
 from repro.kernels.ita_attention.kernel import ita_attention_pallas
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def ita_attention(
@@ -26,7 +22,6 @@ def ita_attention(
     block_q: int = 256,
     block_k: int = 512,
     kv_valid: int | None = None,
-    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Fused int8 MHA with streaming ITAMax. Returns int8 [B, H, Sq, D].
 
@@ -34,8 +29,6 @@ def ita_attention(
     ``kv_valid`` masks padded KV rows (callers that pad Sk to a block
     multiple pass the true length).
     """
-    if interpret is None:
-        interpret = _default_interpret()
     b, h, sq, d = q_q.shape
     _, hkv, sk, _ = k_q.shape
     assert h % hkv == 0
@@ -56,7 +49,7 @@ def ita_attention(
         block_q=block_q,
         block_k=block_k,
         kv_valid=kv_valid,
-        interpret=interpret,
+        interpret=interpret_mode(),
     )
     return out.reshape(b, h, sq, d)
 
@@ -72,7 +65,6 @@ def ita_decode(
     s_v: float,
     s_out: float,
     block_k: int = 512,
-    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Fused int8 decode step (serve_step hot loop).
 
@@ -84,8 +76,6 @@ def ita_decode(
     buckets cache lengths so ``cache_len`` is static per compiled variant
     (dynamic lengths would use scalar prefetch — noted in DESIGN.md).
     """
-    if interpret is None:
-        interpret = _default_interpret()
     b, h, sq, d = q_q.shape
     assert sq == 1, "decode takes exactly one new token"
     _, hkv, smax, _ = k_cache.shape
@@ -105,6 +95,6 @@ def ita_decode(
         block_q=g,
         block_k=min(block_k, smax),
         kv_valid=cache_len,
-        interpret=interpret,
+        interpret=interpret_mode(),
     )
     return out.reshape(b, h, 1, d)

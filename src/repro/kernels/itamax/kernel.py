@@ -15,14 +15,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 from repro.core import itamax as im
 
 
-def _itamax_kernel(x_ref, lut_ref, o_ref):
-    # Pallas forbids closure-captured constants: the exp LUT is an operand.
-    o_ref[...] = im.itamax_rowwise(x_ref[...], lut=lut_ref[0])
+def _itamax_kernel(x_ref, o_ref):
+    o_ref[...] = im.itamax_rowwise(x_ref[...])
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
@@ -34,16 +31,12 @@ def itamax_pallas(
 ) -> jnp.ndarray:
     r, n = logits.shape
     assert r % block_rows == 0, (r, block_rows)
-    lut = im.exp_lut()[None, :]  # (1, 32) int32
     return pl.pallas_call(
         _itamax_kernel,
         grid=(r // block_rows,),
-        in_specs=[
-            pl.BlockSpec((block_rows, n), lambda i: (i, 0)),
-            pl.BlockSpec((1, 32), lambda i: (0, 0)),
-        ],
+        in_specs=[pl.BlockSpec((block_rows, n), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((block_rows, n), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, n), jnp.int8),
-        compiler_params=CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(logits, lut)
+    )(logits)

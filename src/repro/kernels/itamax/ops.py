@@ -2,10 +2,10 @@
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.interpret import interpret_mode
 from repro.kernels.itamax.kernel import itamax_pallas
 
 
@@ -13,15 +13,12 @@ def itamax(
     logits: jnp.ndarray,  # int8 [..., n]
     *,
     block_rows: int = 256,
-    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Rowwise integer softmax over the last axis. int8 -> int8 (A, scale 2^-7)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     *lead, n = logits.shape
     r = int(np.prod(lead)) if lead else 1
     block_rows = min(block_rows, r)
     out = itamax_pallas(
-        logits.reshape(r, n), block_rows=block_rows, interpret=interpret
+        logits.reshape(r, n), block_rows=block_rows, interpret=interpret_mode()
     )
     return out.reshape(*lead, n)

@@ -12,13 +12,42 @@ integer quant scales).
 for the request-level serving engine (``repro.deploy.engine.Engine``):
 request count, generation budget and the sampling policy — so the serve
 CLI and the throughput benchmark present one surface.
+
+``enable_compile_cache`` places JAX's persistent compilation cache for
+every entry point that runs the model.
 """
 
 from __future__ import annotations
 
 import argparse
+import os
 
 from repro.core.heterogeneous import Backend, as_backend
+
+#: The checkout this package runs from, when it runs from one (an editable
+#: install or ``src`` on the path); ``None`` for an installed package.
+_ROOT = os.path.normpath(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                                      "..", "..", ".."))
+CHECKOUT = _ROOT if os.path.isfile(os.path.join(_ROOT, "pyproject.toml")) else None
+
+
+def enable_compile_cache() -> str | None:
+    """Turn on JAX's persistent compilation cache; return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is the directory: JAX reads it
+    itself and nothing here overrides it.  Otherwise the cache goes to one
+    fixed path, ``.jax_cache/`` in the checkout, since a cache whose
+    directory moves between runs is never found again.  An installed
+    package has no checkout and gets no cache (``None``).  Call before the
+    first compile.
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path and CHECKOUT is not None:
+        import jax
+
+        path = os.path.join(CHECKOUT, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path or None
 
 
 def plan_backend_names() -> tuple[str, ...]:

@@ -1,7 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede every other import (jax locks the device count on first init).
-
 """Multi-pod dry-run (deliverable e).
 
 For every (architecture x input-shape) cell, lower + compile the real step
@@ -32,6 +28,7 @@ on the identical quantized params.
 
 import argparse
 import json
+import os
 import time
 import traceback
 
@@ -349,8 +346,12 @@ def run_via_plan(
     return 0 if exact else 1
 
 
+#: Host devices the multi-pod dry run lays its production meshes over.
+DRYRUN_HOST_DEVICES = 512
+
+
 def main(argv=None):
-    from repro.launch.cli import add_plan_args
+    from repro.launch.cli import add_plan_args, enable_compile_cache
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
@@ -375,6 +376,7 @@ def main(argv=None):
     if args.via_plan:
         if not args.arch:
             raise SystemExit("--via-plan requires --arch")
+        enable_compile_cache()
         return run_via_plan(
             args.arch,
             reduced_cfg=args.reduced,
@@ -388,6 +390,12 @@ def main(argv=None):
             use_cache=not args.no_plan_cache,
         )
 
+    # the production meshes need 512 host devices; the flag is read when
+    # the backend first starts, so it is appended here, before any device use
+    os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+        os.environ.get("XLA_FLAGS"),
+        f"--xla_force_host_platform_device_count={DRYRUN_HOST_DEVICES}",
+    )))
     archs = [args.arch] if args.arch else [a for a in list_archs()[:10]]
     shapes = [args.shape] if args.shape else [c.name for c in ALL_SHAPES]
     pods = {"off": [False], "on": [True], "both": [False, True]}[args.multi_pod]

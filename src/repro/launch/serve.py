@@ -89,8 +89,11 @@ def serve_encoder(model, *, batch_size: int, steps: int) -> None:
 
 def serve_decoder(model, *, max_batch: int, requests: int, prompt_len: int,
                   extra_prompt: int, gen: int, sampling,
-                  scheduler=None) -> None:
-    """Request-level serving: submit → schedule → stream, engine-only."""
+                  scheduler=None) -> int:
+    """Request-level serving: submit → schedule → stream, engine-only.
+
+    Returns the exit code: 0 when every request finished with ``eos`` or
+    ``length``, 1 otherwise."""
     from repro.deploy.engine import Engine
     from repro.launch.cli import synthesize_prompts
 
@@ -119,6 +122,11 @@ def serve_decoder(model, *, max_batch: int, requests: int, prompt_len: int,
     for h in handles[:2]:
         print(f"  request {h.rid}: prompt {len(h.prompt)} tokens -> "
               f"{h.tokens[:8]} ({h.finish_reason})")
+    unfinished = [h for h in handles if h.finish_reason not in ("eos", "length")]
+    for h in unfinished:
+        print(f"  request {h.rid} ended {h.finish_reason!r} after "
+              f"{len(h.tokens)} of {gen} tokens")
+    return 1 if unfinished else 0
 
 
 def main(argv=None):
@@ -129,6 +137,7 @@ def main(argv=None):
         add_sanitize_args,
         add_serving_args,
         apply_sanitize_args,
+        enable_compile_cache,
         make_sampling,
         make_scheduler_from_args,
         resolve_requests,
@@ -147,6 +156,7 @@ def main(argv=None):
                   "always plan-backed (compile() -> Engine/InferenceSession)")
     args = ap.parse_args(argv)
     apply_sanitize_args(args)  # before any engine/allocator exists
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -156,7 +166,8 @@ def main(argv=None):
     except UnsupportedFamilyError as e:
         raise SystemExit(f"cannot serve {cfg.name}: {e}")
     if model.kind == "encoder":
-        return serve_encoder(model, batch_size=args.batch, steps=args.gen)
+        serve_encoder(model, batch_size=args.batch, steps=args.gen)
+        return 0
     return serve_decoder(
         model,
         max_batch=args.batch,
@@ -170,4 +181,4 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
-    main()
+    raise SystemExit(main())

@@ -205,7 +205,8 @@ def isilu_i8(x_q: jnp.ndarray, s_in: float, s_out: float) -> jnp.ndarray:
     qp = make_qparams(s_in, 1.0, im.ITAMAX_LOGIT_SCALE)
     v = requantize_wide(x_q, qp.mult, qp.shift, out_bits=14)  # log-grid value
     t = jnp.clip(jnp.abs(v), 0, 1 << 13)
-    e = im._exp2_int(t, im.exp_lut(), im.EXP_LUT_BITS)  # ~256 * e^-|x|
+    # ~256 * e^-|x|; an XLA op, so the table is read by a gather
+    e = im._exp2_int(t, im.EXP_LUT, im.EXP_LUT_BITS, lookup=im.lut_gather)
     denom = 256 + e
     sig_pos = (256 * 256) // denom  # x >= 0 branch, Q8 in [128, 256]
     sig_neg = (256 * e) // denom  # x < 0 branch, Q8 in [0, 128]

@@ -85,8 +85,6 @@ def pipelined_apply(
     assert b % n_micro == 0, (b, n_micro)
     x_micro = x.reshape(n_micro, b // n_micro, *x.shape[1:])
 
-    from jax.experimental.shard_map import shard_map
-
     params_spec = jax.tree.map(lambda _: P(pipe_axis), params_stacked)
     other_axes = [a for a in mesh.axis_names if a != pipe_axis]
 
@@ -96,12 +94,12 @@ def pipelined_apply(
         stage_params = jax.tree.map(lambda a: a[0], stage_params)  # strip stage dim
         return body(stage_params, xm)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         per_stage,
         mesh=mesh,
         in_specs=(params_spec, P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     out = fn(params_stacked, x_micro)
     return out.reshape(b, *out.shape[2:])

@@ -14,11 +14,10 @@ COMPRESSED_PSUM = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp, numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.optim import compression
 
-mesh = jax.make_mesh((4,), ("pod",))
+mesh = jax.make_mesh((4,), ("pod",), axis_types=(AxisType.Auto,))
 g = jax.random.normal(jax.random.PRNGKey(0), (4, 2048)) * 0.01
 err = jnp.zeros_like(g)
 
@@ -26,7 +25,7 @@ def body(g, err):
     out, new_err = compression.compressed_psum(g[0], err[0], "pod")
     return out[None], new_err[None]
 
-fn = shard_map(body, mesh=mesh, in_specs=(P("pod"), P("pod")), out_specs=(P("pod"), P("pod")))
+fn = jax.shard_map(body, mesh=mesh, in_specs=(P("pod"), P("pod")), out_specs=(P("pod"), P("pod")))
 out, new_err = fn(g, err)
 want = np.asarray(g).sum(0)
 got = np.asarray(out)[0]

@@ -137,13 +137,28 @@ class TestFlash:
 
 class TestExpLut:
     def test_lut_values(self):
-        lut = np.asarray(im.exp_lut())
+        lut = im.EXP_LUT
         want = np.round(256 * 2.0 ** (-np.arange(32) / 32))
         np.testing.assert_array_equal(lut, want)
 
     def test_exp2_decomposition(self):
         # exp over the full int8 delta range tracks 2^(-t/32)
         t = jnp.arange(0, 256, dtype=jnp.int32)
-        val = np.asarray(im._exp2_int(t, im.exp_lut(), im.EXP_LUT_BITS), np.float64)
+        val = np.asarray(im._exp2_int(t, im.EXP_LUT, im.EXP_LUT_BITS), np.float64)
         want = 256 * 2.0 ** (-np.arange(256) / 32.0)
         assert np.max(np.abs(val - want)) <= 1.0
+
+    @pytest.mark.parametrize("lookup", ["lut_lookup", "lut_gather"])
+    @pytest.mark.parametrize("table", ["EXP_LUT", "EXP_LUT7", "RENORM_LUT"])
+    def test_lookup_equals_indexing(self, table, lookup):
+        """The select-tree lookup (which the TPU kernel compiler accepts)
+        and the XLA gather return exactly ``lut[r]`` for every index, in
+        any array shape."""
+        lut, fn = getattr(im, table), getattr(im, lookup)
+        r = jnp.arange(32, dtype=jnp.int32)
+        want = jnp.asarray(lut)[r]
+        np.testing.assert_array_equal(np.asarray(fn(lut, r)), np.asarray(want))
+        r2 = jnp.asarray(np.random.default_rng(0).integers(0, 32, (8, 64)), jnp.int32)
+        got = fn(lut, r2)
+        assert got.dtype == jnp.int32
+        np.testing.assert_array_equal(np.asarray(got), lut[np.asarray(r2)])
