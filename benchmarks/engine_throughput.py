@@ -123,7 +123,7 @@ def _run_open_loop(model, prompts, specs, *, max_batch: int, gen: int,
 
 def _closed_loop(args, model, prompts, n, make_sampling):
     print("mode,max_batch,requests,tokens,decode_dispatches,"
-          "dispatches_per_step,step_p50_ms,step_p99_ms,occupancy,tok_per_s,"
+          "step_p50_ms,step_p99_ms,occupancy,tok_per_s,"
           "ttft_p50_ms,ttft_p99_ms,tpot_p50_ms,tpot_p99_ms,"
           "preemptions,requeues,shed,verify_ms,"
           "prefix_hit_blocks,prefix_hit_rate,blocks_shared,cow_copies")
@@ -133,7 +133,7 @@ def _closed_loop(args, model, prompts, n, make_sampling):
                               sampling=make_sampling(args))
         rows[mode] = stats
         print(f"{mode},{mb},{n},{stats.tokens_generated},"
-              f"{stats.decode_dispatches},{stats.dispatches_per_step},"
+              f"{stats.decode_dispatches},"
               f"{stats.step_latency_p50() * 1e3:.2f},"
               f"{stats.step_latency_p99() * 1e3:.2f},"
               f"{stats.occupancy():.2f},{stats.tokens_per_s():.1f},"
@@ -150,9 +150,7 @@ def _closed_loop(args, model, prompts, n, make_sampling):
     dispatch_ratio = serial.decode_dispatches / max(cont.decode_dispatches, 1)
     print(f"# continuous batching: {speedup:.2f}x tok/s over serial "
           f"({dispatch_ratio:.1f}x fewer decode dispatches, "
-          f"{cont.slots_recycled} slots recycled); plan runs "
-          f"{cont.dispatches_per_step} dispatches/step (region fusion; "
-          f"compile(fuse=False) to compare unfused)")
+          f"{cont.slots_recycled} slots recycled)")
 
 
 def _calibrate_slo_ms(model, prompts, *, max_batch: int, gen: int, sampling):

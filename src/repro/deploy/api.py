@@ -67,6 +67,7 @@ from repro.deploy.lowering import (  # noqa: F401 (re-exports)
     lower,
 )
 from repro.deploy.plan import DecoderPlanPair, DeploymentPlan
+from repro.deploy.trace import span
 
 #: Bumped whenever lowering/executor changes can alter plan *content* or
 #: *semantics*.  Cached plans from other versions are recompiled.
@@ -646,14 +647,16 @@ class InferenceSession:
             self._pos = None  # HOST int32 [B] per-slot depth (numpy: the
             # decode hot path must not round-trip to the device per token)
             if pair.paged:
-                self._chunk_fn = jax.jit(
-                    lambda w, pl, t, st, bt: execute_prefill_paged(
-                        pair, w, pl, t, st, bt, backend=be, table=tb)
-                )
-                self._decode_fn = jax.jit(
-                    lambda w, pl, t, p, bt, act: execute_decode_paged(
-                        pair, w, pl, t, p, bt, act, backend=be, table=tb)
-                )
+                def chunk_step(w, pl, t, st, bt):
+                    return execute_prefill_paged(pair, w, pl, t, st, bt,
+                                                 backend=be, table=tb)
+
+                def decode_step(w, pl, t, p, bt, act):
+                    return execute_decode_paged(pair, w, pl, t, p, bt, act,
+                                                backend=be, table=tb)
+
+                self._chunk_fn = jax.jit(chunk_step)
+                self._decode_fn = jax.jit(decode_step)
                 cfgm = model.cfg
                 shape = (cfgm.n_layers, pair.kv_blocks + 1, cfgm.n_kv_heads,
                          pair.kv_block_size, cfgm.head_dim)
@@ -670,27 +673,30 @@ class InferenceSession:
                 # copy-on-write: one jitted whole-block pool copy with
                 # *traced* src/dst indices, so every COW reuses the same
                 # executable instead of retracing per block id
-                self._copy_fn = jax.jit(
-                    lambda p, src, dst: {
-                        "k": p["k"].at[:, dst].set(p["k"][:, src]),
-                        "v": p["v"].at[:, dst].set(p["v"][:, src]),
-                    }
-                )
+                def cow_copy(p, src, dst):
+                    return {"k": p["k"].at[:, dst].set(p["k"][:, src]),
+                            "v": p["v"].at[:, dst].set(p["v"][:, src])}
+
+                self._copy_fn = jax.jit(cow_copy)
                 self._cow_copies = 0
             else:
-                self._prefill_fn = jax.jit(
-                    lambda w, b: execute_prefill(pair, w, b, backend=be, table=tb)
-                )
-                self._decode_fn = jax.jit(
-                    lambda w, c, t, p: execute_decode(pair, w, c, t, pos=p,
-                                                      backend=be, table=tb)
-                )
+                def prefill_step(w, b):
+                    return execute_prefill(pair, w, b, backend=be, table=tb)
+
+                def decode_step(w, c, t, p):
+                    return execute_decode(pair, w, c, t, pos=p, backend=be,
+                                          table=tb)
+
+                self._prefill_fn = jax.jit(prefill_step)
+                self._decode_fn = jax.jit(decode_step)
         else:
             plan = model.artifact
             self._plan = plan
-            self._forward_fn = jax.jit(
-                lambda w, b: execute(plan, w, b, backend=be, table=tb)
-            )
+
+            def encoder_forward(w, b):
+                return execute(plan, w, b, backend=be, table=tb)
+
+            self._forward_fn = jax.jit(encoder_forward)
         self._owner_ident: int | None = None  # thread affinity (lazy bind)
 
     # -- shared ------------------------------------------------------------
@@ -733,13 +739,14 @@ class InferenceSession:
         features [B, S, D]) or a ready batch dict keyed by input name.
         """
         self._require("encoder", "forward")
-        batch = x if isinstance(x, dict) else {self._plan.inputs[0]: jnp.asarray(x)}
-        lead = batch[self._plan.inputs[0]].shape[0]
-        if lead != self.batch_size:
-            raise ValueError(
-                f"batch dim {lead} != session batch_size {self.batch_size}"
-            )
-        return self._forward_fn(self.weights, batch)
+        with span("session.forward"):
+            batch = x if isinstance(x, dict) else {self._plan.inputs[0]: jnp.asarray(x)}
+            lead = batch[self._plan.inputs[0]].shape[0]
+            if lead != self.batch_size:
+                raise ValueError(
+                    f"batch dim {lead} != session batch_size {self.batch_size}"
+                )
+            return self._forward_fn(self.weights, batch)
 
     # -- decoder -----------------------------------------------------------
 
@@ -775,8 +782,8 @@ class InferenceSession:
         """Top-level dispatches per decode step — ``len(decode.nodes)``.
 
         Fused plans collapse same-engine runs into single FusedRegion
-        dispatches, so this is the metric the fusion pass moves (the
-        engine reports it as ``EngineStats.dispatches_per_step``)."""
+        dispatches, so this is the count the fusion pass moves.  The whole
+        step is one device program (``decode_step``) either way."""
         self._require("decoder", "decode_dispatch_count")
         return len(self._pair.decode.nodes)
 
@@ -1014,66 +1021,67 @@ class InferenceSession:
         """
         self._require("decoder", "prefill_chunks")
         self._affine("prefill_chunks")
-        if not self._pair.paged:
-            raise RuntimeError(
-                "prefill_chunks needs a paged session; compile with "
-                "kv_block_size/kv_blocks"
-            )
-        if not chunks:
-            raise ValueError("prefill_chunks needs at least one slot chunk")
-        s = self._pair.seq_len
-        if self._pos is None:
-            self._pos = np.zeros((self.batch_size,), np.int32)
-        checked: dict[int, tuple] = {}
-        for slot, (tokens, start) in sorted(chunks.items()):
-            slot = int(slot)
-            if not 0 <= slot < self.batch_size:
-                raise IndexError(
-                    f"slot {slot} out of range [0, {self.batch_size})")
-            tokens = self._check_tokens(tokens, 1)
-            start = int(start)
-            if start != 0 and not 0 < start <= int(self._pos[slot]):
-                raise ValueError(
-                    f"chunk at start {start} leaves a gap: slot {slot} has "
-                    f"{int(self._pos[slot])} rows (chunks must be contiguous "
-                    f"or overlapping)"
+        with span("session.prefill_chunks"):
+            if not self._pair.paged:
+                raise RuntimeError(
+                    "prefill_chunks needs a paged session; compile with "
+                    "kv_block_size/kv_blocks"
                 )
-            if start + s > self._pair.max_len:
-                raise KVCapacityError([slot], [start], self._pair.max_len)
-            checked[slot] = (tokens, start)
-        # host-side state changes after ALL validation; release-then-grow
-        # is idempotent per slot, so a KVCapacityError mid-loop (pool
-        # exhaustion) is safely retried for the surviving slots
-        for slot, (_, start) in checked.items():
-            if start == 0:
-                self._release_blocks(slot)
-        for slot, (_, start) in checked.items():
-            self._grow_table(slot, blocks_for_rows(start + s,
-                                                   self._pair.kv_block_size))
-        for slot, (_, start) in checked.items():
-            # a suffix chunk overlapping an attached shared prefix (the
-            # pinned tail chunk of a near-full match) re-writes identical
-            # rows — bit-neutral, but still a write: COW keeps the
-            # no-write-into-shared-blocks invariant unconditional
-            self._cow_range(slot, start, start + s)
-        for slot, (_, start) in checked.items():
-            self._note_writes(slot, start, start + s)
-        batch_tokens = np.zeros((self.batch_size, s), np.int32)
-        starts = np.zeros((self.batch_size,), np.int32)
-        # parked lanes write through all-scratch tables — handing them
-        # their live tables would scatter placeholder K/V into real rows
-        tables = np.full_like(self._tables, SCRATCH_BLOCK)
-        for slot, (tokens, start) in checked.items():
-            batch_tokens[slot] = np.asarray(tokens[0])
-            starts[slot] = start
-            tables[slot] = self._tables[slot]
-        logits, self._pool = self._chunk_fn(
-            self.weights, self._pool, jnp.asarray(batch_tokens),
-            jnp.asarray(starts), jnp.asarray(tables),
-        )
-        for slot, (_, start) in checked.items():
-            self._pos[slot] = start + s
-        return logits
+            if not chunks:
+                raise ValueError("prefill_chunks needs at least one slot chunk")
+            s = self._pair.seq_len
+            if self._pos is None:
+                self._pos = np.zeros((self.batch_size,), np.int32)
+            checked: dict[int, tuple] = {}
+            for slot, (tokens, start) in sorted(chunks.items()):
+                slot = int(slot)
+                if not 0 <= slot < self.batch_size:
+                    raise IndexError(
+                        f"slot {slot} out of range [0, {self.batch_size})")
+                tokens = self._check_tokens(tokens, 1)
+                start = int(start)
+                if start != 0 and not 0 < start <= int(self._pos[slot]):
+                    raise ValueError(
+                        f"chunk at start {start} leaves a gap: slot {slot} has "
+                        f"{int(self._pos[slot])} rows (chunks must be contiguous "
+                        f"or overlapping)"
+                    )
+                if start + s > self._pair.max_len:
+                    raise KVCapacityError([slot], [start], self._pair.max_len)
+                checked[slot] = (tokens, start)
+            # host-side state changes after ALL validation; release-then-grow
+            # is idempotent per slot, so a KVCapacityError mid-loop (pool
+            # exhaustion) is safely retried for the surviving slots
+            for slot, (_, start) in checked.items():
+                if start == 0:
+                    self._release_blocks(slot)
+            for slot, (_, start) in checked.items():
+                self._grow_table(slot, blocks_for_rows(start + s,
+                                                       self._pair.kv_block_size))
+            for slot, (_, start) in checked.items():
+                # a suffix chunk overlapping an attached shared prefix (the
+                # pinned tail chunk of a near-full match) re-writes identical
+                # rows — bit-neutral, but still a write: COW keeps the
+                # no-write-into-shared-blocks invariant unconditional
+                self._cow_range(slot, start, start + s)
+            for slot, (_, start) in checked.items():
+                self._note_writes(slot, start, start + s)
+            batch_tokens = np.zeros((self.batch_size, s), np.int32)
+            starts = np.zeros((self.batch_size,), np.int32)
+            # parked lanes write through all-scratch tables — handing them
+            # their live tables would scatter placeholder K/V into real rows
+            tables = np.full_like(self._tables, SCRATCH_BLOCK)
+            for slot, (tokens, start) in checked.items():
+                batch_tokens[slot] = np.asarray(tokens[0])
+                starts[slot] = start
+                tables[slot] = self._tables[slot]
+            logits, self._pool = self._chunk_fn(
+                self.weights, self._pool, jnp.asarray(batch_tokens),
+                jnp.asarray(starts), jnp.asarray(tables),
+            )
+            for slot, (_, start) in checked.items():
+                self._pos[slot] = start + s
+            return logits
 
     def free_slot(self, slot: int) -> None:
         """Release one slot's KV state (paged: return its blocks to the
@@ -1263,72 +1271,72 @@ class InferenceSession:
         """
         self._require("decoder", "decode")
         self._affine("decode")
-        paged = self._pair.paged
-        if (self._kv is None) if not paged else (self._pos is None):
-            raise RuntimeError("decode before prefill: no KV state in the session")
-        if active is not None and not paged:
-            raise ValueError(
-                "active lane masks are a paged-session feature (dense "
-                "dispatches park free lanes at pos 0 instead)"
-            )
-        tokens = jnp.asarray(tokens, jnp.int32)
-        if tokens.ndim == 1:
-            tokens = tokens[:, None]
-        if tokens.shape != (self.batch_size, 1):
-            raise ValueError(
-                f"decode tokens must be [{self.batch_size}, 1], got "
-                f"{tuple(tokens.shape)}"
-            )
-        # pos/active stay on the host: capacity checks and the +1 advance
-        # are numpy, so a decode step costs exactly one device dispatch
-        # (int(jnp.max(pos)) here used to sync per token — the ISSUE 5
-        # hot-path fix).
-        pos = self._pos if pos is None else np.asarray(pos, np.int32)
-        if pos.shape != (self.batch_size,):
-            raise ValueError(
-                f"pos must be a per-request vector [{self.batch_size}], got "
-                f"{tuple(pos.shape)}"
-            )
-        act = (np.ones((self.batch_size,), bool) if active is None
-               else np.asarray(active, bool).reshape(-1))
-        if act.shape != (self.batch_size,):
-            raise ValueError(
-                f"active must be a per-request mask [{self.batch_size}], "
-                f"got {tuple(act.shape)}"
-            )
-        # past-capacity writes would silently clamp inside the scatter and
-        # corrupt the deepest cache row, so bound them loudly instead —
-        # with the offending slots attached, so a scheduler can evict
-        # exactly those and re-dispatch the rest.
-        full = [b for b in range(self.batch_size)
-                if act[b] and int(pos[b]) >= self._pair.max_len]
-        if full:
-            raise KVCapacityError(full, [int(pos[b]) for b in full],
-                                  self._pair.max_len)
-        if paged:
-            # crossing into a new logical block allocates it up front —
-            # pool exhaustion surfaces as a structured error BEFORE any
-            # device state changes, naming the evictable block holders
-            bs = self._pair.kv_block_size
-            for b in range(self.batch_size):
-                if act[b] and int(pos[b]) % bs == 0:
-                    self._grow_table(b, int(pos[b]) // bs + 1)
-            for b in range(self.batch_size):
-                if act[b]:
-                    # first append into a shared partial block (an
-                    # attached prefix whose tail block siblings/the index
-                    # still reference) materializes a private copy
-                    self._cow_range(b, int(pos[b]), int(pos[b]) + 1)
-                    self._note_writes(b, int(pos[b]), int(pos[b]) + 1)
-            logits, self._pool = self._decode_fn(
-                self.weights, self._pool, tokens, jnp.asarray(pos),
-                jnp.asarray(self._tables), jnp.asarray(act),
-            )
-        else:
-            logits, cache = self._decode_fn(self.weights, self._kv, tokens,
-                                            jnp.asarray(pos))
-            self._kv = {"k": cache["k"], "v": cache["v"]}
-        self._pos = np.where(act, pos + 1,
-                             self._pos if self._pos is not None else 0
-                             ).astype(np.int32)
-        return logits
+        with span("session.decode"):
+            paged = self._pair.paged
+            if (self._kv is None) if not paged else (self._pos is None):
+                raise RuntimeError("decode before prefill: no KV state in the session")
+            if active is not None and not paged:
+                raise ValueError(
+                    "active lane masks are a paged-session feature (dense "
+                    "dispatches park free lanes at pos 0 instead)"
+                )
+            tokens = jnp.asarray(tokens, jnp.int32)
+            if tokens.ndim == 1:
+                tokens = tokens[:, None]
+            if tokens.shape != (self.batch_size, 1):
+                raise ValueError(
+                    f"decode tokens must be [{self.batch_size}, 1], got "
+                    f"{tuple(tokens.shape)}"
+                )
+            # pos/active stay on the host: capacity checks and the +1 advance
+            # are numpy, so a decode step costs exactly one device dispatch
+            # (an int(jnp.max(pos)) here would sync with the device per token).
+            pos = self._pos if pos is None else np.asarray(pos, np.int32)
+            if pos.shape != (self.batch_size,):
+                raise ValueError(
+                    f"pos must be a per-request vector [{self.batch_size}], got "
+                    f"{tuple(pos.shape)}"
+                )
+            act = (np.ones((self.batch_size,), bool) if active is None
+                   else np.asarray(active, bool).reshape(-1))
+            if act.shape != (self.batch_size,):
+                raise ValueError(
+                    f"active must be a per-request mask [{self.batch_size}], "
+                    f"got {tuple(act.shape)}"
+                )
+            # past-capacity writes would silently clamp inside the scatter and
+            # corrupt the deepest cache row, so bound them loudly instead —
+            # with the offending slots attached, so a scheduler can evict
+            # exactly those and re-dispatch the rest.
+            full = [b for b in range(self.batch_size)
+                    if act[b] and int(pos[b]) >= self._pair.max_len]
+            if full:
+                raise KVCapacityError(full, [int(pos[b]) for b in full],
+                                      self._pair.max_len)
+            if paged:
+                # crossing into a new logical block allocates it up front —
+                # pool exhaustion surfaces as a structured error BEFORE any
+                # device state changes, naming the evictable block holders
+                bs = self._pair.kv_block_size
+                for b in range(self.batch_size):
+                    if act[b] and int(pos[b]) % bs == 0:
+                        self._grow_table(b, int(pos[b]) // bs + 1)
+                for b in range(self.batch_size):
+                    if act[b]:
+                        # first append into a shared partial block (an
+                        # attached prefix whose tail block siblings/the index
+                        # still reference) materializes a private copy
+                        self._cow_range(b, int(pos[b]), int(pos[b]) + 1)
+                        self._note_writes(b, int(pos[b]), int(pos[b]) + 1)
+                logits, self._pool = self._decode_fn(
+                    self.weights, self._pool, tokens, jnp.asarray(pos),
+                    jnp.asarray(self._tables), jnp.asarray(act),
+                )
+            else:
+                logits, cache = self._decode_fn(self.weights, self._kv, tokens,
+                                                jnp.asarray(pos))
+                self._kv = {"k": cache["k"], "v": cache["v"]}
+            self._pos = np.where(act, pos + 1,
+                                 self._pos if self._pos is not None else 0
+                                 ).astype(np.int32)
+            return logits

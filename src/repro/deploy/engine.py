@@ -88,6 +88,7 @@ from repro.deploy.serving.scheduler import (
     Scheduler,
     effective_deadline,
 )
+from repro.deploy.trace import span
 
 
 # ---------------------------------------------------------------------------
@@ -164,8 +165,9 @@ class RequestHandle:
     urgent), ``ttft_slo_ms`` (time-to-first-token target) and
     ``deadline_ms`` (completion budget — past it the request is
     preemptible).  ``arrival_t`` / ``first_token_t`` / ``finish_t`` are
-    engine-clock timestamps; ``preemptions`` counts how many times this
-    request was evicted-to-queue and re-admitted.
+    engine-clock timestamps, and so is ``admitted_t``, stamped when the
+    request first leaves the queue for a slot; ``preemptions`` counts how
+    many times this request was evicted-to-queue and re-admitted.
     """
 
     def __init__(self, engine: "Engine", rid: int, prompt: tuple[int, ...],
@@ -192,6 +194,7 @@ class RequestHandle:
                            else self.arrival_t + float(deadline_ms) / 1e3)
         self.admit_deadline_t = effective_deadline(
             self.arrival_t, ttft_slo_ms, deadline_ms)
+        self.admitted_t: float | None = None
         self.first_token_t: float | None = None
         self._last_token_t: float | None = None
         self.finish_t: float | None = None
@@ -230,6 +233,20 @@ class RequestHandle:
                 f"finish_reason={self.finish_reason!r})")
 
 
+#: newest samples each :class:`EngineStats` list keeps: a long-running
+#: server would otherwise grow them without end, and every snapshot
+#: copies them under the engine lock
+SAMPLES = 4096
+
+
+def _record(samples: list, value: float) -> None:
+    """Append ``value``, dropping the oldest sample past :data:`SAMPLES`
+    (in place: the list object stays the one callers hold)."""
+    samples.append(value)
+    if len(samples) > SAMPLES:
+        del samples[: len(samples) - SAMPLES]
+
+
 def _nearest_rank(xs: list, pct: float) -> float:
     """Nearest-rank percentile of a sample list (0.0 when empty)."""
     if not xs:
@@ -262,6 +279,7 @@ class EngineStats:
     slots_busy: int = 0
     prefill_time_s: float = 0.0
     decode_time_s: float = 0.0
+    sample_time_s: float = 0.0     # host time inside the sampling policy
     # prefix cache (zero everywhere unless compile(prefix_cache=True)):
     # lookups/hits count admissions, hit_blocks counts KV blocks served
     # from the cache instead of re-prefilled, full_prefix_hits are
@@ -277,9 +295,6 @@ class EngineStats:
     cow_copies: int = 0
     # parked (index-only) blocks LRU-reclaimed back to the pool
     prefix_reclaimed_blocks: int = 0
-    # top-level plan dispatches per decode step (len(decode.nodes)) — the
-    # metric region fusion collapses (~5x on the reference decoders)
-    dispatches_per_step: int = 0
     # one-time static-verification cost of the artifact this engine runs
     # (CompiledModel.verify_ms; 0.0 when compiled with verify=False)
     verify_ms: float = 0.0
@@ -287,13 +302,16 @@ class EngineStats:
     # shadow sanitizer's continuous findings are reported separately —
     # see the "sanitize" section of /v1/stats)
     audit_findings: int = 0
+    # the sample lists keep the newest SAMPLES entries each
     step_times_s: list = dataclasses.field(default_factory=list)
     # request-level latency samples (engine clock): TTFT is submit ->
     # first *generated* token (queue wait + prefill + any preemption
     # included — the number an SLO is written against); TPOT is the gap
-    # between consecutive generated tokens of one request
+    # between consecutive generated tokens of one request; queue wait is
+    # submit -> first admission into a slot
     ttft_s: list = dataclasses.field(default_factory=list)
     tpot_s: list = dataclasses.field(default_factory=list)
+    queue_wait_s: list = dataclasses.field(default_factory=list)
 
     def snapshot(self) -> "EngineStats":
         """One consistent copy: scalar counters plus fresh copies of the
@@ -304,6 +322,7 @@ class EngineStats:
         out.step_times_s = list(self.step_times_s)
         out.ttft_s = list(self.ttft_s)
         out.tpot_s = list(self.tpot_s)
+        out.queue_wait_s = list(self.queue_wait_s)
         out._slo_outcomes = list(self._slo_outcomes)
         return out
 
@@ -324,6 +343,10 @@ class EngineStats:
     def tpot(self, pct: float) -> float:
         """Nearest-rank percentile of observed per-output-token gaps."""
         return _nearest_rank(self.tpot_s, pct)
+
+    def queue_wait(self, pct: float) -> float:
+        """Nearest-rank percentile of observed queue waits (seconds)."""
+        return _nearest_rank(self.queue_wait_s, pct)
 
     def goodput_under_slo(self) -> float:
         """Fraction of *finished* SLO-carrying requests whose TTFT met
@@ -369,6 +392,9 @@ class EngineStats:
                    f"{self.ttft(99) * 1e3:.1f} ms"
                    f", tpot p50/p99 {self.tpot(50) * 1e3:.1f}/"
                    f"{self.tpot(99) * 1e3:.1f} ms")
+        if self.queue_wait_s:
+            slo += (f", queue wait p50/p99 {self.queue_wait(50) * 1e3:.1f}/"
+                    f"{self.queue_wait(99) * 1e3:.1f} ms")
         if self.preemptions or self.shed_requests:
             slo += (f", {self.preemptions} preemptions / "
                     f"{self.requeues} requeues / "
@@ -390,7 +416,7 @@ class EngineStats:
             f"{self.slots_recycled} slots recycled, "
             f"{self.tokens_per_s():.1f} gen tok/s, "
             f"{self.prompt_tokens_per_s():.1f} prompt tok/s, "
-            f"{self.dispatches_per_step} dispatches/step, "
+            f"{self.sample_time_s:.3f} s sampling, "
             f"step p50/p99 {self.step_latency_p50() * 1e3:.1f}/"
             f"{self.step_latency_p99() * 1e3:.1f} ms, "
             f"plan verified in {self.verify_ms:.1f} ms)"
@@ -509,7 +535,6 @@ class Engine:
         self.scheduler.guard_lock = self._lock
         self.stats = EngineStats(
             max_batch=self.max_batch,
-            dispatches_per_step=self.session.decode_dispatch_count,
             verify_ms=getattr(self.session.model, "verify_ms", 0.0))
         self._slots: list[RequestHandle | None] = [None] * self.max_batch
         # engine-owned per-slot depth; free slots are pinned at 0 so their
@@ -675,7 +700,6 @@ class Engine:
         self._cow_base = self.session.cow_copies if self.paged else 0
         self.stats = EngineStats(
             max_batch=self.max_batch,
-            dispatches_per_step=self.session.decode_dispatch_count,
             verify_ms=getattr(self.session.model, "verify_ms", 0.0))
         self._note_queue()
         return self.stats
@@ -691,14 +715,16 @@ class Engine:
         the engine is idle."""
         t_step = time.perf_counter()
         try:
-            return self._step()
+            with span("engine.step"):
+                return self._step()
         finally:
             with self._lock:  # stats mutate under the lock: see snapshot()
-                self.stats.step_times_s.append(time.perf_counter() - t_step)
+                _record(self.stats.step_times_s, time.perf_counter() - t_step)
 
     def _step(self) -> bool:
-        worked = self._preempt()
-        admitted = self._admit()
+        with span("engine.admit"):
+            worked = self._preempt()
+            admitted = self._admit()
         worked = bool(admitted) or worked
         worked = self._advance_chunks() or worked
 
@@ -742,7 +768,8 @@ class Engine:
                         self._finish(self._slots[b], "kv_capacity")
                 active = decode_lanes()
                 continue
-            jax.block_until_ready(logits)
+            with span("engine.device_wait"):
+                jax.block_until_ready(logits)
             with self._lock:
                 self.stats.decode_time_s += time.perf_counter() - t0
                 self.stats.decode_dispatches += 1
@@ -750,7 +777,8 @@ class Engine:
             # ONE device->host fetch for the whole step: per-slot
             # ``logits[b, -1]`` pulls used to round-trip once per resident
             # request per token (ISSUE 5)
-            step_rows = jax.device_get(logits[:, -1])
+            with span("engine.fetch"):
+                step_rows = jax.device_get(logits[:, -1])
             for b in active:
                 if self._slots[b] is None:
                     continue  # evicted mid-loop by a streaming callback
@@ -889,6 +917,9 @@ class Engine:
                         if freed < short:
                             break
                 handle = self.scheduler.pop(now)
+                if handle.admitted_t is None:  # first admission only
+                    handle.admitted_t = now
+                    _record(self.stats.queue_wait_s, now - handle.arrival_t)
             handle.slot = free
             handle.status = RequestStatus.PREFILLING
             self._slots[free] = handle
@@ -925,13 +956,16 @@ class Engine:
                 head = jnp.asarray(prefix[: self.seq_len], jnp.int32)[None]
                 t0 = time.perf_counter()
                 logits = self.session.prefill_slot(free, head)
-                jax.block_until_ready(logits)
+                with span("engine.device_wait"):
+                    jax.block_until_ready(logits)
                 with self._lock:
                     self.stats.prefill_time_s += time.perf_counter() - t0
                     self.stats.prefill_dispatches += 1
                     self.stats.prompt_tokens_prefilled += self.seq_len
                 self._pos[free] = self.seq_len
-                self._consume_logits(free, jax.device_get(logits[0, -1]))
+                with span("engine.fetch"):
+                    row = jax.device_get(logits[0, -1])
+                self._consume_logits(free, row)
             admitted.add(free)
         return admitted
 
@@ -1062,7 +1096,8 @@ class Engine:
             t0 = time.perf_counter()
             try:
                 logits = self.session.prefill_chunks(pending)
-                jax.block_until_ready(logits)
+                with span("engine.device_wait"):
+                    jax.block_until_ready(logits)
             except KVCapacityError as e:
                 # requester-pays, like decode capacity: the pool cannot
                 # hold the named slots' prompts right now, so those
@@ -1098,7 +1133,8 @@ class Engine:
                 if final_rows is None:
                     # ONE device->host fetch covers every slot that
                     # finishes chunking this step
-                    final_rows = jax.device_get(logits[:, -1])
+                    with span("engine.fetch"):
+                        final_rows = jax.device_get(logits[:, -1])
                 if self.prefix_index is not None:
                     # index the finished prefix NOW, before the consume
                     # below can finish the request and free its chain:
@@ -1132,23 +1168,28 @@ class Engine:
             with self._lock:
                 self.stats.prompt_tokens_forced += 1
             return
-        tok = int(self.sampling(logits_row, handle.rid, len(handle.tokens)))
+        t0 = time.perf_counter()
+        with span("sampling"):
+            tok = int(self.sampling(logits_row, handle.rid, len(handle.tokens)))
+        sampled_s = time.perf_counter() - t0
         handle.status = RequestStatus.DECODING
         handle.tokens.append(tok)
         now = self.clock()
         with self._lock:
+            self.stats.sample_time_s += sampled_s
             self.stats.tokens_generated += 1
             if handle.first_token_t is None:
                 handle.first_token_t = now
-                self.stats.ttft_s.append(handle.ttft_s)
+                _record(self.stats.ttft_s, handle.ttft_s)
                 if handle.ttft_slo_ms is not None:
                     self.stats._slo_outcomes.append(
                         handle.ttft_s <= handle.ttft_slo_ms / 1e3)
             else:
-                self.stats.tpot_s.append(now - handle._last_token_t)
+                _record(self.stats.tpot_s, now - handle._last_token_t)
             handle._last_token_t = now
         if handle.on_token is not None:
-            handle.on_token(tok)
+            with span("engine.stream"):
+                handle.on_token(tok)
             if handle.done:  # the callback cancelled this very request
                 return
         if handle.eos_id is not None and tok == handle.eos_id:

@@ -312,7 +312,8 @@ def _compile_region(node: PlanNode, table, backend) -> Callable:
     def region_fn(*args):
         env = dict(zip(in_names, args))
         for b, run in body:
-            env[b.outputs[0]] = run(env)
+            with jax.named_scope(_scope(b)):
+                env[b.outputs[0]] = run(env)
         return tuple(env[t] for t in out_names)
 
     jitted = jax.jit(region_fn)
@@ -328,6 +329,11 @@ def _compile_region(node: PlanNode, table, backend) -> Callable:
         return jitted(*args)
 
     return run
+
+
+def _scope(node: PlanNode) -> str:
+    """The named scope a node's device ops carry in their ``op_name``."""
+    return f"{node.kind}.{node.name}"
 
 
 def _compile_node(node: PlanNode, table, backend) -> Callable:
@@ -401,11 +407,12 @@ def execute(
     for name in plan.inputs:
         env[name] = batch[name]
     for node, run in program:
-        if node.fused:
-            for name, val in zip(node.outputs, run(env)):
-                env[name] = val
-        else:
-            env[node.outputs[0]] = run(env)
+        with jax.named_scope(_scope(node)):
+            if node.fused:
+                for name, val in zip(node.outputs, run(env)):
+                    env[name] = val
+            else:
+                env[node.outputs[0]] = run(env)
     outs = [env[name] for name in plan.outputs]
     return outs[0] if len(outs) == 1 else tuple(outs)
 
@@ -595,8 +602,9 @@ def _stack_cache(plan: DeploymentPlan, outs_by_name: dict, length) -> dict:
     ``{"k": [L, B, Hkv, M, D], "v": ..., "len": int32}``."""
     ks = [outs_by_name[out] for _, out in plan.kv_state[0::2]]
     vs = [outs_by_name[out] for _, out in plan.kv_state[1::2]]
-    return {"k": jnp.stack(ks), "v": jnp.stack(vs),
-            "len": jnp.asarray(length, jnp.int32)}
+    with jax.named_scope("kv_pool.stack"):
+        return {"k": jnp.stack(ks), "v": jnp.stack(vs),
+                "len": jnp.asarray(length, jnp.int32)}
 
 
 def execute_prefill(
@@ -638,8 +646,9 @@ def execute_decode(
     if pos is None:
         pos = cache["len"]
     batch = {"token": token, "pos": pos}
-    for i, (cin, _) in enumerate(plan.kv_state):
-        batch[cin] = cache["k" if i % 2 == 0 else "v"][i // 2]
+    with jax.named_scope("kv_pool.slice"):
+        for i, (cin, _) in enumerate(plan.kv_state):
+            batch[cin] = cache["k" if i % 2 == 0 else "v"][i // 2]
     outs = execute(plan, weights, batch, backend=backend, table=table)
     outs_by_name = dict(zip(plan.outputs, outs))
     cache_out = _stack_cache(plan, outs_by_name, pos + 1)
@@ -656,13 +665,15 @@ def _stack_pool(plan: DeploymentPlan, outs_by_name: dict) -> dict:
     the pool is shared across request slots by construction)."""
     ks = [outs_by_name[out] for _, out in plan.kv_state[0::2]]
     vs = [outs_by_name[out] for _, out in plan.kv_state[1::2]]
-    return {"k": jnp.stack(ks), "v": jnp.stack(vs)}
+    with jax.named_scope("kv_pool.stack"):
+        return {"k": jnp.stack(ks), "v": jnp.stack(vs)}
 
 
 def _paged_batch(plan: DeploymentPlan, pool: dict, extra: dict) -> dict:
     batch = dict(extra)
-    for i, (cin, _) in enumerate(plan.kv_state):
-        batch[cin] = pool["k" if i % 2 == 0 else "v"][i // 2]
+    with jax.named_scope("kv_pool.slice"):
+        for i, (cin, _) in enumerate(plan.kv_state):
+            batch[cin] = pool["k" if i % 2 == 0 else "v"][i // 2]
     return batch
 
 

@@ -37,6 +37,7 @@ from typing import Callable, Sequence
 from repro.deploy.api import CompiledModel, InferenceSession
 from repro.deploy.engine import Engine, RequestHandle, RequestStatus
 from repro.deploy.sanitize import make_condition
+from repro.deploy.trace import span
 
 
 class AsyncRequestHandle:
@@ -279,7 +280,8 @@ class AsyncEngine:
             while True:
                 with self._cv:
                     while not self._stop and not self._work_pending():
-                        self._cv.wait()
+                        with span("engine.no_work"):
+                            self._cv.wait()
                     if self._stop and (not self._drain_on_stop
                                        or not self._work_pending()):
                         break

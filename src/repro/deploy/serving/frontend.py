@@ -70,6 +70,9 @@ def _stats_payload(engine: AsyncEngine) -> dict:
         "ttft_p99_ms": s.ttft(99) * 1e3,
         "tpot_p50_ms": s.tpot(50) * 1e3,
         "tpot_p99_ms": s.tpot(99) * 1e3,
+        "queue_wait_p50_ms": s.queue_wait(50) * 1e3,
+        "queue_wait_p99_ms": s.queue_wait(99) * 1e3,
+        "sample_time_s": s.sample_time_s,
         "goodput_under_slo": s.goodput_under_slo(),
         "step_p50_ms": s.step_latency_p50() * 1e3,
         "step_p99_ms": s.step_latency_p99() * 1e3,

@@ -463,20 +463,53 @@ class TestStepLatencyStats:
         assert s.step_latency_p50() == pytest.approx(0.050)
         assert s.step_latency_p99() == pytest.approx(0.099)
         assert s.step_latency_s(100.0) == pytest.approx(0.100)
-        # the summary carries the new counters
-        s.dispatches_per_step = 7
-        assert "7 dispatches/step" in s.summary()
+        # the summary carries the sampling time and the queue waits
+        s.sample_time_s = 1.25
+        s.queue_wait_s[:] = [0.002, 0.004]
+        assert "1.250 s sampling" in s.summary()
+        assert "queue wait p50/p99 2.0/4.0 ms" in s.summary()
 
     def test_engine_records_steps_and_dispatches(self, olmo):
         cfg, params = olmo
-        engine = Engine(_compile(cfg), 2, params=params)
-        assert engine.stats.dispatches_per_step == \
-            engine.session.decode_dispatch_count
-        engine.submit(_prompts(cfg, 1, lengths=(SEQ,), seed=3)[0], 2)
+        clock = iter(float(t) for t in range(10**6))
+        engine = Engine(_compile(cfg), 2, params=params, clock=lambda: next(clock))
+        prompts = _prompts(cfg, 3, lengths=(SEQ,), seed=3)
+        handles = [engine.submit(p, 2) for p in prompts]
         engine.run_until_idle(max_steps=50)
-        assert len(engine.stats.step_times_s) > 0
-        assert engine.stats.step_latency_p99() >= engine.stats.step_latency_p50() > 0
-        # reset starts a fresh record but keeps the per-step dispatch count
+        s = engine.stats
+        assert len(s.step_times_s) > 0
+        assert s.step_latency_p99() >= s.step_latency_p50() > 0
+        assert s.decode_dispatches > 0 and s.prefill_dispatches == 3
+        # every sampled token passed through the policy's clock
+        assert s.tokens_generated == 6 and s.sample_time_s > 0
+        # one queue wait per admission, on the engine's clock: the third
+        # request waited for a slot two requests held
+        assert all(h.admitted_t is not None for h in handles)
+        assert s.queue_wait_s == [h.admitted_t - h.arrival_t for h in handles]
+        assert handles[2].admitted_t - handles[2].arrival_t > \
+            handles[0].admitted_t - handles[0].arrival_t >= 0
+        # a snapshot copies the counters and lists
+        snap = engine.stats_snapshot()
+        assert snap.sample_time_s == s.sample_time_s
+        assert snap.queue_wait_s == s.queue_wait_s
+        assert snap.queue_wait_s is not s.queue_wait_s
+        # reset starts a fresh record
         fresh = engine.reset_stats()
-        assert fresh.step_times_s == []
-        assert fresh.dispatches_per_step == engine.session.decode_dispatch_count
+        assert fresh.step_times_s == [] and fresh.queue_wait_s == []
+        assert fresh.sample_time_s == 0.0
+
+
+def test_sample_lists_keep_the_newest_samples():
+    """A long-running server keeps the newest SAMPLES step times, TTFT,
+    TPOT and queue waits, trimmed in place."""
+    from repro.deploy import engine as E
+
+    s = E.EngineStats(max_batch=1)
+    lists = (s.step_times_s, s.ttft_s, s.tpot_s, s.queue_wait_s)
+    for xs in lists:
+        for i in range(E.SAMPLES + 10):
+            E._record(xs, float(i))
+    for xs in lists:
+        assert len(xs) == E.SAMPLES
+        assert xs[0] == 10.0 and xs[-1] == float(E.SAMPLES + 9)
+    assert (s.step_times_s, s.ttft_s, s.tpot_s, s.queue_wait_s) == lists

@@ -533,6 +533,9 @@ class TestFrontend:
         stats = http_get_json(host, port, "/v1/stats")
         assert stats["requests_completed"] >= 1
         assert stats["goodput_under_slo"] == pytest.approx(1.0)
+        # sampling time and queue waits, from the engine's counters
+        assert stats["sample_time_s"] > 0
+        assert 0 <= stats["queue_wait_p50_ms"] <= stats["queue_wait_p99_ms"]
         assert http_get_json(host, port, "/healthz")["status"] == "ok"
 
     def test_bad_request_is_structured_400(self, olmo, served):
