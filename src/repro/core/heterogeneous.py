@@ -125,7 +125,9 @@ class DispatchTable:
     ``register(..., backend=...)`` installs a backend-specific override —
     the mechanism by which the accelerator slot holds the paper-faithful
     XLA arithmetic under ``Backend.W8A8`` and the Pallas kernel under
-    ``Backend.ITA`` simultaneously.
+    ``Backend.ITA`` simultaneously.  A cluster slot can be overridden the
+    same way (``silumul`` runs a Pallas kernel under ``Backend.ITA``)
+    without moving the op to the accelerator.
     """
 
     table: dict[str, dict[Engine, Callable]] = dataclasses.field(default_factory=dict)
@@ -237,6 +239,8 @@ def populate_default_table(table: DispatchTable | None = None) -> DispatchTable:
     from repro.kernels import igelu as igelu_pallas
     from repro.kernels import int8_gemm as int8_gemm_pallas
     from repro.kernels import ita_attention as ita_attention_pallas
+    from repro.kernels import silumul as silumul_pallas
+    from repro.kernels import silumul_ref
     from repro.models import layers as L
     from repro.quant.qparams import make_qparams, requantize
 
@@ -494,14 +498,11 @@ def populate_default_table(table: DispatchTable | None = None) -> DispatchTable:
 
     table.register("attn_paged", Engine.CLUSTER, _attn_paged)
 
-    def _silu_mul(g_q, u_q, *, scales):
-        s_g, s_u, s_out = scales
-        sg = L.isilu_i8(g_q, s_g, s_g)
-        prod = jnp.asarray(sg, jnp.int32) * jnp.asarray(u_q, jnp.int32)
-        qp = make_qparams(s_g, s_u, s_out)
-        return requantize(prod, qp.mult, qp.shift)
-
-    table.register("silumul", Engine.CLUSTER, _silu_mul)
+    # -- silumul: SwiGLU's gated product, a cluster op on every backend;
+    # under ``ita`` it runs as a Pallas kernel (the XLA runner reads the
+    # exp2 table through a gather over the whole activation)
+    table.register("silumul", Engine.CLUSTER, silumul_ref)
+    table.register("silumul", Engine.CLUSTER, silumul_pallas, backend=Backend.ITA)
     table.register("lasttok", Engine.CLUSTER, lambda x_q: x_q[:, -1:])
 
     def _lm_head(h_q, w_q, *, scale, tied):

@@ -9,9 +9,11 @@ the oracle (interpret=True on CPU, compiled on TPU).
 - ``ita_attention``  : fused int8 MHA with streaming ITAMax (flash form)
 - ``itamax``         : standalone rowwise integer softmax
 - ``igelu``          : standalone elementwise i-GeLU
+- ``silumul``        : SwiGLU's gated product with the integer SiLU
 """
 
 from repro.kernels.igelu import igelu, igelu_ref  # noqa: F401
 from repro.kernels.int8_gemm import int8_gemm, int8_gemm_ref  # noqa: F401
 from repro.kernels.ita_attention import ita_attention, ita_attention_ref  # noqa: F401
 from repro.kernels.itamax import itamax, itamax_ref  # noqa: F401
+from repro.kernels.silumul import silumul, silumul_ref  # noqa: F401

@@ -195,25 +195,56 @@ def apply_rope_i8(x_q: jnp.ndarray, c_q: jnp.ndarray, s_q: jnp.ndarray) -> jnp.n
     return jnp.clip(y, -128, 127).astype(jnp.int8)
 
 
-def isilu_i8(x_q: jnp.ndarray, s_in: float, s_out: float) -> jnp.ndarray:
+def isilu_params(s_in: float, s_out: float) -> tuple[int, int, int, int]:
+    """Static ``(mult, shift)`` pairs of :func:`isilu_int`: the input onto
+    the log2 grid, then the Q8 product ``x * sigma(x)`` onto ``s_out``."""
+    qp = make_qparams(s_in, 1.0, im.ITAMAX_LOGIT_SCALE)
+    qo = make_qparams(s_in, 1.0 / 256.0, s_out)
+    return qp.mult, qp.shift, qo.mult, qo.shift
+
+
+def isilu_int(x_q: jnp.ndarray, log_mult: int, log_shift: int, out_mult: int,
+              out_shift: int, lookup=im.lut_gather) -> jnp.ndarray:
     """Integer SiLU (cluster op — ITA's activation unit has no SiLU mode).
 
     sigma(x) = 2^(x*log2 e) / (1 + 2^(x*log2 e)) evaluated with the ITAMax
     exp2 machinery: requantize x onto the log2 grid, exponentiate with the
-    8-bit LUT, one integer division per element.
+    8-bit LUT, one integer division per element.  ``lookup`` reads the
+    table: the default XLA gather, or :func:`~repro.core.itamax.lut_lookup`
+    inside a Pallas kernel; both return the same codes.
     """
-    qp = make_qparams(s_in, 1.0, im.ITAMAX_LOGIT_SCALE)
-    v = requantize_wide(x_q, qp.mult, qp.shift, out_bits=14)  # log-grid value
+    v = requantize_wide(x_q, log_mult, log_shift, out_bits=14)  # log-grid value
     t = jnp.clip(jnp.abs(v), 0, 1 << 13)
-    # ~256 * e^-|x|; an XLA op, so the table is read by a gather
-    e = im._exp2_int(t, im.EXP_LUT, im.EXP_LUT_BITS, lookup=im.lut_gather)
+    e = im._exp2_int(t, im.EXP_LUT, im.EXP_LUT_BITS, lookup=lookup)  # ~256 * e^-|x|
     denom = 256 + e
     sig_pos = (256 * 256) // denom  # x >= 0 branch, Q8 in [128, 256]
     sig_neg = (256 * e) // denom  # x < 0 branch, Q8 in [0, 128]
     sig = jnp.where(v >= 0, sig_pos, sig_neg)
     acc = jnp.asarray(x_q, jnp.int32) * sig  # scale s_in / 256
-    qo = make_qparams(s_in, 1.0 / 256.0, s_out)
-    return requantize(acc, qo.mult, qo.shift)
+    return requantize(acc, out_mult, out_shift)
+
+
+def isilu_i8(x_q: jnp.ndarray, s_in: float, s_out: float) -> jnp.ndarray:
+    """:func:`isilu_int` at float scales, the table read by a gather."""
+    return isilu_int(x_q, *isilu_params(s_in, s_out))
+
+
+def silumul_params(s_g: float, s_u: float, s_out: float) -> tuple[int, ...]:
+    """Static ints of :func:`silumul_int`: :func:`isilu_params` of the gate
+    (SiLU kept on the gate's grid), then the product ``silu(g) * u`` onto
+    ``s_out``."""
+    qp = make_qparams(s_g, s_u, s_out)
+    return (*isilu_params(s_g, s_g), qp.mult, qp.shift)
+
+
+def silumul_int(g_q: jnp.ndarray, u_q: jnp.ndarray, params: tuple[int, ...],
+                lookup=im.lut_gather) -> jnp.ndarray:
+    """SwiGLU's gated product ``requant(isilu(g) * u)`` (the plan's
+    ``silumul`` op), int8 in and out."""
+    *silu, mult, shift = params
+    sg = isilu_int(g_q, *silu, lookup=lookup)
+    prod = jnp.asarray(sg, jnp.int32) * jnp.asarray(u_q, jnp.int32)
+    return requantize(prod, mult, shift)
 
 
 def silu_i8_ref_f32(x):

@@ -27,6 +27,9 @@ from repro.kernels.igelu.kernel import igelu_pallas
 from repro.kernels.int8_gemm.kernel import int8_gemm_pallas
 from repro.kernels.ita_attention.kernel import ita_attention_pallas
 from repro.kernels.itamax.kernel import itamax_pallas
+from repro.kernels.silumul.kernel import silumul_pallas
+from repro.kernels.silumul.ops import ROW_TILE
+from repro.models import layers as L
 from repro.quant.qparams import make_qparams
 
 
@@ -115,3 +118,18 @@ def test_itamax(one_chip):
         return itamax_pallas(x, block_rows=256, interpret=False)
 
     assert "tpu_custom_call" in _compiled_text(fn, one_chip, ((8 * 4 * 128, 128), jnp.int8))
+
+
+@pytest.mark.parametrize("m", [4096, 32], ids=["chunk", "decode"])
+def test_silumul(one_chip, m):
+    """SwiGLU's gated product at olmo-1b's MLP width, as the ``ita``
+    runner tiles it: the chunk step (32 lanes x 128 tokens) and the decode
+    step (32 lanes)."""
+    params = L.silumul_params(0.05, 0.05, 0.05)
+
+    def fn(g, u):
+        return silumul_pallas(g, u, params=params, block_m=ROW_TILE, block_n=512,
+                              interpret=False)
+
+    shape = ((m, 8192), jnp.int8)
+    assert "tpu_custom_call" in _compiled_text(fn, one_chip, shape, shape)

@@ -62,3 +62,50 @@ class TestDispatch:
         t = _table()
         engine, _ = t.resolve(het.OpDesc("layernorm", shapes=((128, 128),)), het.Backend.W8A8)
         assert engine is het.Engine.CLUSTER
+
+
+class TestSilumulDispatch:
+    """SwiGLU's gated product stays a cluster op on every backend; under
+    ``ita`` the cluster slot runs the Pallas kernel, elsewhere the XLA
+    runner."""
+
+    @pytest.mark.parametrize("backend", list(het.Backend), ids=lambda b: b.value)
+    def test_runner_per_backend(self, backend):
+        from repro.kernels.silumul import silumul, silumul_ref
+
+        op = het.OpDesc("silumul", shapes=((32, 128, 8192), (32, 128, 8192)))
+        engine, fn = het.DEFAULT_TABLE.resolve(op, backend)
+        assert engine is het.Engine.CLUSTER
+        assert fn is (silumul if backend is het.Backend.ITA else silumul_ref)
+        assert "silumul" not in het.ACCEL_KINDS
+
+    def test_ita_serves_the_w8a8_greedy_tokens(self):
+        """A tiny SwiGLU decoder served through the paged engine (chunked
+        prompts, decode steps) gives the same greedy tokens on both
+        backends."""
+        import jax
+
+        from repro.configs import get_config, reduced
+        from repro.deploy import api
+        from repro.deploy.engine import Engine
+        from repro.models import transformer as T
+
+        cfg = reduced(get_config("olmo-1b"))
+        assert cfg.mlp == "swiglu"
+        params = T.init_params(cfg, jax.random.PRNGKey(5))
+        key = jax.random.PRNGKey(11)
+        prompts = [[int(t) for t in jax.random.randint(jax.random.fold_in(key, i), (n,), 0,
+                                                        cfg.vocab)]
+                   for i, n in enumerate((8, 19, 12))]
+
+        def serve(backend):
+            model = api.compile(cfg, backend=backend, seq_len=8, max_len=40, use_cache=False,
+                                kv_block_size=8, kv_blocks=16)
+            engine = Engine(model, 2, params=params)
+            handles = [engine.submit(p, 4) for p in prompts]
+            engine.run_until_idle(max_steps=200)
+            return [h.tokens for h in handles]
+
+        w8a8 = serve("w8a8")
+        assert all(len(t) == 4 for t in w8a8)
+        assert serve("ita") == w8a8

@@ -19,6 +19,8 @@ from repro.kernels import (
     ita_attention_ref,
     itamax,
     itamax_ref,
+    silumul,
+    silumul_ref,
 )
 
 
@@ -143,6 +145,48 @@ class TestIGeluKernel:
         got = igelu(x, in_scale=scale, out_scale=scale, block_m=bm, block_n=bn)
         want = igelu_ref(x, in_scale=scale, out_scale=scale)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def _code_pairs(*shape):
+    """Gate and up codes that run through the 256 x 256 (gate, up) pairs in
+    order, again from the start after the last; every pair once in 65,536
+    elements."""
+    idx = np.arange(int(np.prod(shape))) % (256 * 256)
+    g = (idx // 256 - 128).astype(np.int8).reshape(shape)
+    u = (idx % 256 - 128).astype(np.int8).reshape(shape)
+    return jnp.asarray(g), jnp.asarray(u)
+
+
+OLMO_SCALES = (0.05, 0.05, 0.05)
+
+
+class TestSilumulKernel:
+    """The ita runner of SwiGLU's gated product against the XLA runner."""
+
+    @pytest.mark.parametrize("scales", [OLMO_SCALES, (0.02, 0.08, 0.05), (0.1, 0.03, 0.07)],
+                             ids=["olmo-1b", "narrow-gate", "wide-gate"])
+    def test_every_code_pair(self, scales):
+        g, u = _code_pairs(256, 256)
+        got = silumul(g, u, scales=scales)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(silumul_ref(g, u, scales=scales)))
+
+    # M = 1 and 96 pad rows up to the int8 row tile; 4096 x 8192 is
+    # olmo-1b's chunk step (32 lanes x 128 tokens), 32 x 8192 its decode step
+    @pytest.mark.parametrize("f", [128, 8192])
+    @pytest.mark.parametrize("m", [1, 32, 96, 4096])
+    def test_shapes(self, m, f):
+        g, u = _code_pairs(m, f)
+        got = silumul(g, u, scales=OLMO_SCALES)
+        np.testing.assert_array_equal(np.asarray(got),
+                                      np.asarray(silumul_ref(g, u, scales=OLMO_SCALES)))
+
+    @pytest.mark.parametrize("shape", [(2, 3, 8192), (32, 1, 8192), (4, 16, 256)])
+    def test_leading_dims(self, shape):
+        g, u = _code_pairs(*shape)
+        got = silumul(g, u, scales=OLMO_SCALES)
+        assert got.shape == shape and got.dtype == jnp.int8
+        np.testing.assert_array_equal(np.asarray(got),
+                                      np.asarray(silumul_ref(g, u, scales=OLMO_SCALES)))
 
 
 class TestItaDecodeKernel:
