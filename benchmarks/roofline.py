@@ -62,8 +62,8 @@ def param_count(cfg) -> tuple[float, float]:
         return n, n
     if cfg.n_experts:
         expert = 3 * e * cfg.d_ff_expert
-        moe_total = cfg.n_experts * expert + cfg.n_shared_experts * 3 * e * cfg.d_ff_expert
-        moe_active = cfg.top_k * expert + cfg.n_shared_experts * 3 * e * cfg.d_ff_expert
+        moe_total = cfg.n_experts * expert + 3 * e * cfg.shared_width
+        moe_active = cfg.top_k * expert + 3 * e * cfg.shared_width
         per_shared = attn
         total = cfg.n_layers * (per_shared + moe_total) + 2 * cfg.vocab_padded * e
         active = cfg.n_layers * (per_shared + moe_active) + 2 * cfg.vocab_padded * e

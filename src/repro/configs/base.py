@@ -38,7 +38,8 @@ class ArchConfig:
     top_k: int = 0
     n_shared_experts: int = 0
     d_ff_expert: int = 0
-    capacity_factor: float = 1.25
+    norm_topk_prob: bool = True  # renormalise the top-k router weights
+    capacity_factor: float = 1.25  # GShard dispatch of the float training path
 
     # SSM (Mamba2 / SSD)
     ssm_state: int = 0
@@ -70,6 +71,12 @@ class ArchConfig:
         vocab dim divides the model axis (Megatron-style padding; padded
         logits are masked in the loss)."""
         return ((self.vocab + 255) // 256) * 256 if self.vocab else 0
+
+    @property
+    def shared_width(self) -> int:
+        """Width of the shared-expert SwiGLU block, its experts fused into one
+        (0: none).  The block's output is scaled by ``sigmoid(x . w_g)``."""
+        return self.n_shared_experts * self.d_ff_expert
 
     @property
     def is_attention_free(self) -> bool:

@@ -65,7 +65,7 @@ class OpDesc:
 
 
 #: ops the accelerator datapath supports at all
-ACCEL_KINDS = {"gemm", "mha", "relu", "gelu", "identity"}
+ACCEL_KINDS = {"gemm", "expert_gemm", "mha", "relu", "gelu", "identity"}
 
 
 def backend_granule(backend: "Backend") -> int:
@@ -218,9 +218,17 @@ def populate_default_table(table: DispatchTable | None = None) -> DispatchTable:
                       kv_heads, head_dim, block_size)
                       -> int8 pool [P+1, Hkv, block_size, D] (scatter at
                       per-lane rows; inactive lanes land in scratch)
-      silumul:     fn(gate_q, up_q, *, scales) -> int8
+      silumul:     fn(gate_q, up_q, *, scales, live_rows=None) -> int8
+                      (rows from live_rows on are zero: an expert
+                      layer's padding, skipped by the kernel)
       lasttok:     fn(x_q) -> int8 (last sequence position)
       lmhead:      fn(h_q, w_q, *, scale, tied) -> float32
+
+    The expert layer's grouped GEMM (the plan's ``ExpertGemm``; the other
+    expert-layer nodes are cluster code in ``repro.models.moe``):
+
+      expert_gemm: fn(x_q, tiles, w_q, *, scales, row_tile) -> int8 [P, N]
+                   (rows grouped by expert, ``w_q`` [E, K, N])
     """
     table = DEFAULT_TABLE if table is None else table
 
@@ -239,6 +247,8 @@ def populate_default_table(table: DispatchTable | None = None) -> DispatchTable:
     from repro.kernels import igelu as igelu_pallas
     from repro.kernels import int8_gemm as int8_gemm_pallas
     from repro.kernels import ita_attention as ita_attention_pallas
+    from repro.kernels import expert_gemm as expert_gemm_pallas
+    from repro.kernels import expert_gemm_ref
     from repro.kernels import silumul as silumul_pallas
     from repro.kernels import silumul_ref
     from repro.models import layers as L
@@ -504,6 +514,11 @@ def populate_default_table(table: DispatchTable | None = None) -> DispatchTable:
     table.register("silumul", Engine.CLUSTER, silumul_ref)
     table.register("silumul", Engine.CLUSTER, silumul_pallas, backend=Backend.ITA)
     table.register("lasttok", Engine.CLUSTER, lambda x_q: x_q[:, -1:])
+
+    # -- expert_gemm: the dropless grouped int8 GEMM of an expert layer
+    table.register("expert_gemm", Engine.CLUSTER, expert_gemm_ref)
+    table.register("expert_gemm", Engine.ACCELERATOR, expert_gemm_ref, backend=Backend.W8A8)
+    table.register("expert_gemm", Engine.ACCELERATOR, expert_gemm_pallas, backend=Backend.ITA)
 
     def _lm_head(h_q, w_q, *, scale, tied):
         w = w_q.astype(jnp.int8).T if tied else w_q.astype(jnp.int8)

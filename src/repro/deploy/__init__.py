@@ -78,7 +78,7 @@ from repro.deploy.api import (  # noqa: F401
     UnsupportedFamilyError,
     compile,
     config_fingerprint,
-    is_dense_decoder,
+    is_decoder_lm,
 )
 from repro.deploy.paging import (  # noqa: F401
     BlockAllocator,

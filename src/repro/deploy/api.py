@@ -62,8 +62,9 @@ from repro.core.heterogeneous import (
     backend_granule,
 )
 from repro.deploy.lowering import (  # noqa: F401 (re-exports)
+    EXPERT_COUNTS,
     UnsupportedFamilyError,
-    is_dense_decoder,
+    is_decoder_lm,
     lower,
 )
 from repro.deploy.plan import DecoderPlanPair, DeploymentPlan
@@ -294,6 +295,12 @@ class CompiledModel:
     def bind(self, params: dict | None = None, *, key=None) -> tuple[dict, dict]:
         """(float init ->) PTQ quantize -> bind onto the plan's weight names.
 
+        ``params`` may also be an already quantized pytree (the layout
+        ``quantize_params`` gives: int8 codes, int32 biases, int8 norm
+        gammas, on its grids; ``layers`` stacked or a list of per-layer
+        trees), recognised by its ``*_q`` tables: it is bound as it is,
+        so int8 weights never travel as floats.
+
         Returns ``(weights, qp)``; ``qp`` is the quantized param pytree so
         callers can run the model-level reference path on identical ints.
         """
@@ -310,7 +317,10 @@ class CompiledModel:
         if params is None:
             key = jax.random.PRNGKey(0) if key is None else key
             params = M.init_params(self.cfg, key)
-        qp = M.quantize_params(self.cfg, params)
+        if "pos_q" in params or "table_q" in params.get("embed", {}):
+            qp = params
+        else:
+            qp = M.quantize_params(self.cfg, params)
         return bind_fn(plan, self.cfg, qp), qp
 
     def session(
@@ -488,7 +498,7 @@ def compile(  # noqa: A001 — torch.compile precedent
     be = as_backend(backend)
     granule = backend_granule(be)
     s = seq_len or cfg.max_seq
-    is_decoder = is_dense_decoder(cfg)
+    is_decoder = is_decoder_lm(cfg)
     if (kv_block_size is None) != (kv_blocks is None):
         raise ValueError(
             "kv_block_size and kv_blocks come as a pair (both set the "
@@ -647,13 +657,18 @@ class InferenceSession:
             self._pos = None  # HOST int32 [B] per-slot depth (numpy: the
             # decode hot path must not round-trip to the device per token)
             if pair.paged:
-                def chunk_step(w, pl, t, st, bt):
-                    return execute_prefill_paged(pair, w, pl, t, st, bt,
-                                                 backend=be, table=tb)
+                # ``acc``: the expert layers' counters, summed on the device
+                # over steps until the engine fetches them with a step's
+                # logits (None for a plan without expert layers)
+                def chunk_step(w, pl, t, st, bt, acc=None):
+                    logits, pool, n = execute_prefill_paged(
+                        pair, w, pl, t, st, bt, backend=be, table=tb)
+                    return logits, pool, None if n is None else acc + n
 
-                def decode_step(w, pl, t, p, bt, act):
-                    return execute_decode_paged(pair, w, pl, t, p, bt, act,
-                                                backend=be, table=tb)
+                def decode_step(w, pl, t, p, bt, act, acc=None):
+                    logits, pool, n = execute_decode_paged(
+                        pair, w, pl, t, p, bt, act, backend=be, table=tb)
+                    return logits, pool, None if n is None else acc + n
 
                 self._chunk_fn = jax.jit(chunk_step)
                 self._decode_fn = jax.jit(decode_step)
@@ -679,6 +694,9 @@ class InferenceSession:
 
                 self._copy_fn = jax.jit(cow_copy)
                 self._cow_copies = 0
+                self._expert_zero = (jnp.zeros((2,), jnp.int32)
+                                     if EXPERT_COUNTS in pair.prefill.outputs else None)
+                self._expert_acc = self._expert_zero
             else:
                 def prefill_step(w, b):
                     return execute_prefill(pair, w, b, backend=be, table=tb)
@@ -819,6 +837,18 @@ class InferenceSession:
         return self._alloc if self._pair.paged else None
 
     @property
+    def expert_counts(self):
+        """Device int32 [2]: the (row, expert) pairs routed and the experts
+        with rows, summed over layers and steps since the last
+        :meth:`reset_expert_counts`; None for a plan without expert layers."""
+        return getattr(self, "_expert_acc", None)
+
+    def reset_expert_counts(self) -> None:
+        self._affine("reset_expert_counts")
+        if self.expert_counts is not None:
+            self._expert_acc = self._expert_zero
+
+    @property
     def cow_copies(self) -> int:
         """Copy-on-write block copies materialized so far (paged)."""
         self._require("decoder", "cow_copies")
@@ -882,9 +912,9 @@ class InferenceSession:
                 self._grow_table(b, need)
             for b in range(self.batch_size):
                 self._note_writes(b, 0, s)
-            logits, self._pool = self._chunk_fn(
+            logits, self._pool, self._expert_acc = self._chunk_fn(
                 self.weights, self._pool, tokens, jnp.int32(0),
-                jnp.asarray(self._tables),
+                jnp.asarray(self._tables), self._expert_acc,
             )
         else:
             logits, cache = self._prefill_fn(self.weights, {"tokens": tokens})
@@ -988,9 +1018,9 @@ class InferenceSession:
         self._grow_table(slot, need)
         self._cow_range(slot, start, start + s)
         self._note_writes(slot, start, start + s)
-        logits, self._pool = self._chunk_fn(
+        logits, self._pool, self._expert_acc = self._chunk_fn(
             self.weights, self._pool, tokens, jnp.int32(start),
-            jnp.asarray(self._tables[slot : slot + 1]),
+            jnp.asarray(self._tables[slot : slot + 1]), self._expert_acc,
         )
         self._pos[slot] = start + s
         return logits
@@ -1075,9 +1105,9 @@ class InferenceSession:
                 batch_tokens[slot] = np.asarray(tokens[0])
                 starts[slot] = start
                 tables[slot] = self._tables[slot]
-            logits, self._pool = self._chunk_fn(
+            logits, self._pool, self._expert_acc = self._chunk_fn(
                 self.weights, self._pool, jnp.asarray(batch_tokens),
-                jnp.asarray(starts), jnp.asarray(tables),
+                jnp.asarray(starts), jnp.asarray(tables), self._expert_acc,
             )
             for slot, (_, start) in checked.items():
                 self._pos[slot] = start + s
@@ -1328,9 +1358,9 @@ class InferenceSession:
                         # still reference) materializes a private copy
                         self._cow_range(b, int(pos[b]), int(pos[b]) + 1)
                         self._note_writes(b, int(pos[b]), int(pos[b]) + 1)
-                logits, self._pool = self._decode_fn(
+                logits, self._pool, self._expert_acc = self._decode_fn(
                     self.weights, self._pool, tokens, jnp.asarray(pos),
-                    jnp.asarray(self._tables), jnp.asarray(act),
+                    jnp.asarray(self._tables), jnp.asarray(act), self._expert_acc,
                 )
             else:
                 logits, cache = self._decode_fn(self.weights, self._kv, tokens,

@@ -295,6 +295,11 @@ class EngineStats:
     cow_copies: int = 0
     # parked (index-only) blocks LRU-reclaimed back to the pool
     prefix_reclaimed_blocks: int = 0
+    # expert layers (zero for a plan without them): (row, expert) pairs
+    # routed and experts with at least one row, summed over layers and
+    # steps; counted on the device, fetched with the step's logits
+    expert_rows: int = 0
+    expert_groups: int = 0
     # one-time static-verification cost of the artifact this engine runs
     # (CompiledModel.verify_ms; 0.0 when compiled with verify=False)
     verify_ms: float = 0.0
@@ -778,7 +783,7 @@ class Engine:
             # ``logits[b, -1]`` pulls used to round-trip once per resident
             # request per token (ISSUE 5)
             with span("engine.fetch"):
-                step_rows = jax.device_get(logits[:, -1])
+                step_rows = self._fetch_rows(logits)
             for b in active:
                 if self._slots[b] is None:
                     continue  # evicted mid-loop by a streaming callback
@@ -787,6 +792,20 @@ class Engine:
             break
         self._note_queue()
         return True
+
+    def _fetch_rows(self, logits):
+        """The step's last-token logits on the host.  With expert layers,
+        their counters ride on the same transfer and restart on the
+        device."""
+        acc = self.session.expert_counts if self.paged else None
+        if acc is None:
+            return jax.device_get(logits[:, -1])
+        rows, counts = jax.device_get((logits[:, -1], acc))
+        self.session.reset_expert_counts()
+        with self._lock:
+            self.stats.expert_rows += int(counts[0])
+            self.stats.expert_groups += int(counts[1])
+        return rows
 
     def run_until_idle(self, max_steps: int | None = None) -> EngineStats:
         """Drive :meth:`step` until every submitted request is finished."""
@@ -1134,7 +1153,7 @@ class Engine:
                     # ONE device->host fetch covers every slot that
                     # finishes chunking this step
                     with span("engine.fetch"):
-                        final_rows = jax.device_get(logits[:, -1])
+                        final_rows = self._fetch_rows(logits)
                 if self.prefix_index is not None:
                     # index the finished prefix NOW, before the consume
                     # below can finish the request and free its chain:

@@ -290,6 +290,12 @@ def _compile_cluster(node: PlanNode, table, backend) -> Callable:
         return run
     if kind == "silumul":
         scales = tuple(a["scales"])
+        if len(ins) > 2:  # an expert layer's grouped rows: skip the dead tiles
+            from repro.models import moe
+
+            tile = a["row_tile"]
+            return lambda env: fn(env[ins[0]], env[ins[1]], scales=scales,
+                                  live_rows=moe.live_rows(env[ins[2]], tile))
         return lambda env: fn(env[ins[0]], env[ins[1]], scales=scales)
     if kind == "lasttok":
         return lambda env: fn(env[ins[0]])
@@ -297,6 +303,72 @@ def _compile_cluster(node: PlanNode, table, backend) -> Callable:
         scale, tied = a["scale"], a["tied"]
         return lambda env: fn(env[ins[0]], env[ins[1]], scale=scale, tied=tied)
     raise NotImplementedError(f"no runner for op kind {kind!r} ({node.op})")
+
+
+def _compile_moe(node: PlanNode, table, backend) -> Callable:
+    """Bind one node of an expert layer: the integer functions of
+    :mod:`repro.models.moe`; the grouped GEMM resolves through the
+    dispatch table (Pallas ``expert_gemm`` on ``ita``, its XLA runner
+    otherwise)."""
+    from repro.deploy.paging import SCRATCH_BLOCK
+    from repro.models import moe
+
+    a, ins, op = node.attrs, node.inputs, node.op
+    if op == "MoeRouter":
+        return lambda env: moe.router_logits(env[ins[0]], env[ins[1]])
+    if op == "MoeRoute":
+        s_in, s_w, s_out = a["scales"]
+        qp = moe.make_qparams(s_in, s_w, s_out)
+        kw = dict(top_k=a["top_k"], norm_topk=a["norm_topk"], mult=qp.mult, shift=qp.shift)
+        lanes = a["lanes"]
+
+        def run(env):
+            logits = env[ins[0]]
+            valid = None
+            if lanes == "active":
+                valid = jnp.asarray(env[ins[1]]).astype(bool).reshape(-1)
+            elif lanes == "block_table":
+                # a lane parked on an all-scratch table carries no request
+                bt = jnp.asarray(env[ins[1]], jnp.int32)
+                valid = bt.reshape(-1, bt.shape[-1])[:, 0] != SCRATCH_BLOCK
+            if valid is not None:
+                valid = jnp.broadcast_to(valid[:, None], logits.shape[:-1])
+            return moe.route_int(logits, valid, **kw)
+
+        return run
+    if op == "MoeDispatch":
+        n_e, tile = a["n_experts"], a["row_tile"]
+
+        def run(env):
+            h, ids = env[ins[0]], env[ins[1]]
+            return moe.dispatch(h.reshape(-1, h.shape[-1]), ids.reshape(-1, ids.shape[-1]),
+                                n_e, tile)
+
+        return run
+    if op == "ExpertGemm":
+        _, k, n = a["dims"]
+        fn = _resolve(table, OpDesc("expert_gemm", shapes=((k, n),)), backend)
+        scales, tile = tuple(a["scales"]), a["row_tile"]
+        return lambda env: fn(env[ins[0]], env[ins[1]], env[ins[2]], scales=scales,
+                              row_tile=tile)
+    if op == "MoeCombine":
+        qp = moe.make_qparams(*a["scales"])
+
+        def run(env):
+            w = env[ins[2]]
+            out = moe.combine_int(env[ins[0]], env[ins[1]], w.reshape(-1, w.shape[-1]),
+                                  qp.mult, qp.shift)
+            return out.reshape(*w.shape[:-1], out.shape[-1])
+
+        return run
+    if op == "SharedGate":
+        qp = moe.make_qparams(*a["scales"])
+        table_q = tuple(int(v) for v in moe.sigmoid_table(a["s_gate"]))
+        return lambda env: moe.shared_gate_int(env[ins[0]], env[ins[1]], table_q,
+                                               qp.mult, qp.shift)
+    if op == "MoeCount":
+        return lambda env: sum(moe.expert_counts(env[t]) for t in ins)
+    raise NotImplementedError(f"no runner for expert-layer op {op!r} ({node.name})")
 
 
 def _compile_region(node: PlanNode, table, backend) -> Callable:
@@ -313,7 +385,7 @@ def _compile_region(node: PlanNode, table, backend) -> Callable:
         env = dict(zip(in_names, args))
         for b, run in body:
             with jax.named_scope(_scope(b)):
-                env[b.outputs[0]] = run(env)
+                _store(env, b, run(env))
         return tuple(env[t] for t in out_names)
 
     jitted = jax.jit(region_fn)
@@ -331,6 +403,16 @@ def _compile_region(node: PlanNode, table, backend) -> Callable:
     return run
 
 
+def _store(env: dict, node: PlanNode, value) -> None:
+    """A node's result into ``env``: one array, or a tuple for a fused
+    region and for a node with several outputs (the expert layer's route
+    and dispatch)."""
+    if node.fused or len(node.outputs) > 1:
+        env.update(zip(node.outputs, value))
+    else:
+        env[node.outputs[0]] = value
+
+
 def _scope(node: PlanNode) -> str:
     """The named scope a node's device ops carry in their ``op_name``."""
     return f"{node.kind}.{node.name}"
@@ -346,6 +428,8 @@ def _compile_node(node: PlanNode, table, backend) -> Callable:
         if node.op == "MHAHead":
             return _compile_mha_head(node, table, backend)
         return _compile_mha(node, table, backend)
+    if kind == "moe":
+        return _compile_moe(node, table, backend)
     return _compile_cluster(node, table, backend)
 
 
@@ -408,11 +492,7 @@ def execute(
         env[name] = batch[name]
     for node, run in program:
         with jax.named_scope(_scope(node)):
-            if node.fused:
-                for name, val in zip(node.outputs, run(env)):
-                    env[name] = val
-            else:
-                env[node.outputs[0]] = run(env)
+            _store(env, node, run(env))
     outs = [env[name] for name in plan.outputs]
     return outs[0] if len(outs) == 1 else tuple(outs)
 
@@ -571,6 +651,15 @@ def bind_encoder_weights(plan: DeploymentPlan, cfg: ArchConfig, qp: dict) -> dic
 # Decoder plans: weight binding + KV-cache-threading executors
 # ---------------------------------------------------------------------------
 
+def _layer(qp: dict, l: int) -> dict:
+    """Layer ``l`` of a quantized pytree: its ``layers`` stacked over a
+    leading axis, or a list of per-layer trees (bound without a copy)."""
+    layers = qp["layers"]
+    if isinstance(layers, (list, tuple)):
+        return layers[l]
+    return jax.tree.map(lambda a: a[l], layers)
+
+
 def bind_decoder_weights(plan: DeploymentPlan, cfg: ArchConfig, qp: dict) -> dict:
     """Map decoder plan weight names onto ``transformer.quantize_params``.
 
@@ -582,13 +671,22 @@ def bind_decoder_weights(plan: DeploymentPlan, cfg: ArchConfig, qp: dict) -> dic
     put, put_norm = _weight_binder(weights)
 
     for l in range(cfg.n_layers):
-        lp = jax.tree.map(lambda a: a[l], qp["layers"])
+        lp = _layer(qp, l)
         pre = f"l{l}_"
         _bind_attn_layer(put, put_norm, pre, cfg, lp)
+        mlp = lp["mlp"]
         for mname in ("gate", "up", "down"):
-            if mname in lp["mlp"]:
-                put(pre + mname, lp["mlp"][mname]["w_q"])
-                put(pre + mname + "_b", lp["mlp"][mname].get("b_q"))
+            if mname in mlp:
+                put(pre + mname, mlp[mname]["w_q"])
+                put(pre + mname + "_b", mlp[mname].get("b_q"))
+        if cfg.n_experts:
+            put(pre + "router", mlp["router"]["w_q"])
+            for mname in ("gate", "up", "down"):
+                put(pre + "experts_" + mname, mlp["experts"][mname + "_q"])
+            if "shared" in mlp:
+                for mname, p in mlp["shared"].items():
+                    put(pre + "shared_" + mname + "_proj", p["w_q"])
+                put(pre + "shared_expert_gate", mlp["shared_gate"]["w_q"])
 
     put_norm("final_norm", qp["final_norm"])
     put("embed_table", qp["embed"]["table_q"])
@@ -694,15 +792,24 @@ def execute_prefill_paged(
     the chunk's global token offset (scalar; 0 for the first chunk),
     ``block_table`` int32 [B, blocks_per_slot].  Writes rows
     ``[start, start + S)`` of each lane's logical cache through its block
-    table and returns ``(last-token logits, updated pool)``.
+    table and returns ``(last-token logits, updated pool, expert
+    counts)``; the counts (:data:`~repro.deploy.lowering.EXPERT_COUNTS`)
+    are None for a plan without expert layers.
     """
     plan = pair.prefill
     batch = _paged_batch(plan, pool, {
         "tokens": tokens, "pos": start, "block_table": block_table,
     })
     outs = execute(plan, weights, batch, backend=backend, table=table)
+    return _paged_outputs(plan, outs)
+
+
+def _paged_outputs(plan: DeploymentPlan, outs) -> tuple:
+    from repro.deploy.lowering import EXPERT_COUNTS
+
     outs_by_name = dict(zip(plan.outputs, outs))
-    return outs_by_name[plan.outputs[0]], _stack_pool(plan, outs_by_name)
+    return (outs_by_name[plan.outputs[0]], _stack_pool(plan, outs_by_name),
+            outs_by_name.get(EXPERT_COUNTS))
 
 
 def execute_decode_paged(
@@ -723,7 +830,8 @@ def execute_decode_paged(
     [B, blocks_per_slot], ``active`` bool/int32 [B] — inactive lanes
     (free slots, slots mid-chunked-prefill) dispatch anyway (the batch
     shape is static) but their cache writes land in the scratch block and
-    their logits are discarded by the caller.
+    their logits are discarded by the caller.  Returns what
+    :func:`execute_prefill_paged` returns.
     """
     plan = pair.decode
     batch = _paged_batch(plan, pool, {
@@ -731,5 +839,4 @@ def execute_decode_paged(
         "active": active,
     })
     outs = execute(plan, weights, batch, backend=backend, table=table)
-    outs_by_name = dict(zip(plan.outputs, outs))
-    return outs_by_name[plan.outputs[0]], _stack_pool(plan, outs_by_name)
+    return _paged_outputs(plan, outs)

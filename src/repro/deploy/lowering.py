@@ -37,6 +37,7 @@ from repro.deploy import memory as memlib
 from repro.deploy import patterns, tiler
 from repro.deploy.graph import Graph
 from repro.deploy.plan import DecoderPlanPair, DeploymentPlan, PlanNode, TensorSpec
+from repro.models import moe
 
 # mirrors repro.models.encoder / repro.models.layers defaults
 _S_GAMMA = 1.0 / 64.0
@@ -45,14 +46,15 @@ _DEF_S_RES = 0.08
 _DEF_S_W = 0.01
 
 #: families ``lower()`` can compile today (ROADMAP queues the rest)
-SUPPORTED_FAMILIES = ("encoder", "dense")
+SUPPORTED_FAMILIES = ("encoder", "dense", "moe")
 
 
-def is_dense_decoder(cfg: ArchConfig) -> bool:
+def is_decoder_lm(cfg: ArchConfig) -> bool:
     """Does this config lower to a :class:`DecoderPlanPair`?  The ONE
-    definition of the dense-decoder rule — ``lower()``, ``api.compile``
-    and the launch scripts all branch on this predicate."""
-    return cfg.family == "dense" and not cfg.n_experts
+    definition of the decoder rule — ``lower()``, ``api.compile`` and the
+    launch scripts all branch on this predicate: token decoders whose
+    MLPs are dense or expert layers."""
+    return cfg.family in ("dense", "moe")
 
 
 class UnsupportedFamilyError(NotImplementedError):
@@ -71,7 +73,7 @@ class UnsupportedFamilyError(NotImplementedError):
         msg = (
             f"plan lowering does not support family {cfg.family!r} "
             f"(config {cfg.name!r}); supported families: "
-            f"{', '.join(SUPPORTED_FAMILIES)} (dense decoders without experts)"
+            f"{', '.join(SUPPORTED_FAMILIES)}"
         )
         if detail:
             msg += f" — {detail}"
@@ -183,6 +185,10 @@ def build_runtime_encoder_graph(
 PREFILL_BLOCK_K = 512
 DECODE_BLOCK_K = 2048
 
+#: plan output of a decoder with expert layers: int32 [2], the (row, expert)
+#: pairs routed and the experts with rows, summed over its layers
+EXPERT_COUNTS = "expert_counts"
+
 
 def build_runtime_decoder_graph(
     cfg: ArchConfig,
@@ -246,14 +252,71 @@ def build_runtime_decoder_graph(
                    s_gamma=_S_GAMMA, s_out=s_act)
         return out
 
-    def add_linear(x, w_name, dims, out_name, bias=False, **extra):
+    def add_linear(x, w_name, dims, out_name, bias=False, scales=sc_q, **extra):
         m, k, n = dims
         ins = [x, g.add_tensor(w_name, (k, n), weight=True)]
         if bias:
             ins.append(g.add_tensor(w_name + "_b", (n,), dtype="int32", weight=True))
         out = g.add_tensor(out_name, (m, n))
-        g.add_node("MatMul", ins, [out], dims=dims, scales=sc_q, pad_m=pad_m, **extra)
+        g.add_node("MatMul", ins, [out], dims=dims, scales=scales, pad_m=pad_m, **extra)
         return out
+
+    def add_swiglu(x, pre, f_dim, w_sfx=""):
+        gt = add_linear(x, pre + "gate" + w_sfx, (s, e, f_dim), pre + "gate_out")
+        up = add_linear(x, pre + "up" + w_sfx, (s, e, f_dim), pre + "up_out")
+        sm = g.add_tensor(pre + "silu", (s, f_dim))
+        g.add_node("SiluMul", [gt, up], [sm], dims=(s, f_dim), scales=(s_act, s_act, s_act))
+        return add_linear(sm, pre + "down" + w_sfx, (s, f_dim, e), pre + "down_out")
+
+    def add_expert_layer(h, pre):
+        """The integer expert layer of ``repro.models.moe`` as plan nodes;
+        returns its output (s_act grid) and its dispatch's tile map."""
+        n_e, k, fe = cfg.n_experts, cfg.top_k, cfg.d_ff_expert
+        t, rows = moe.grouped_rows(s * k, n_e, moe.ROW_TILE)
+        logits = g.add_tensor(pre + "router_logits", (s, n_e), dtype="int32")
+        g.add_node("MoeRouter", [h, g.add_tensor(pre + "router", (e, n_e), weight=True)],
+                   [logits], dims=(s, e, n_e))
+        ids = g.add_tensor(pre + "route_ids", (s, k), dtype="int32")
+        wts = g.add_tensor(pre + "route_w", (s, k))
+        # lanes that carry no request route to no expert: the decode step's
+        # inactive lanes, and chunk lanes parked on all-scratch tables
+        lanes = {"decode": "active", "prefill": "block_table"}[phase] if paged else None
+        g.add_node("MoeRoute", [logits] + ([lanes] if lanes else []), [ids, wts],
+                   dims=(s, n_e), top_k=k, norm_topk=cfg.norm_topk_prob,
+                   scales=(s_act, s_w, moe.S_ROUTER), lanes=lanes or "all")
+        xs = g.add_tensor(pre + "expert_x", (rows, e))
+        dest = g.add_tensor(pre + "expert_dest", (s, k), dtype="int32")
+        tiles = g.add_tensor(pre + "expert_tiles", (t + 3,), dtype="int32")
+        g.add_node("MoeDispatch", [h, ids], [xs, dest, tiles], dims=(s, e),
+                   n_experts=n_e, row_tile=moe.ROW_TILE)
+
+        def grouped(x, w_name, k_dim, n_dim, out_name):
+            w = g.add_tensor(w_name, (n_e, k_dim, n_dim), weight=True)
+            out = g.add_tensor(out_name, (rows, n_dim))
+            g.add_node("ExpertGemm", [x, tiles, w], [out], dims=(rows, k_dim, n_dim),
+                       scales=sc_q, row_tile=moe.ROW_TILE)
+            return out
+
+        gt = grouped(xs, pre + "experts_gate", e, fe, pre + "expert_gate_out")
+        up = grouped(xs, pre + "experts_up", e, fe, pre + "expert_up_out")
+        sm = g.add_tensor(pre + "expert_silu", (rows, fe))
+        g.add_node("SiluMul", [gt, up, tiles], [sm], dims=(rows, fe),
+                   scales=(s_act, s_act, s_act), row_tile=moe.ROW_TILE)
+        y = grouped(sm, pre + "experts_down", fe, e, pre + "expert_down_out")
+        out = g.add_tensor(pre + "routed", (s, e))
+        g.add_node("MoeCombine", [y, dest, wts], [out], dims=(s, e),
+                   scales=(s_act, moe.S_WEIGHT, s_act))
+        if cfg.shared_width:
+            sh = add_swiglu(h, pre + "shared_", cfg.shared_width, w_sfx="_proj")
+            code = add_linear(h, pre + "shared_expert_gate", (s, e, 1),
+                              pre + "shared_gate_code", scales=(s_act, s_w, moe.S_GATE))
+            gated = g.add_tensor(pre + "shared_gated", (s, e))
+            g.add_node("SharedGate", [sh, code], [gated], dims=(s, e),
+                       scales=(s_act, moe.S_SIGMOID, s_act), s_gate=moe.S_GATE)
+            both = g.add_tensor(pre + "expert_out", (s, e))
+            g.add_node("Add", [out, gated], [both], dims=(s, e), scales=(s_act, s_act, s_act))
+            out = both
+        return out, tiles
 
     # -- prologue: token embedding (the embed table is on the s_res grid)
     tok_name = "tokens" if phase == "prefill" else "token"
@@ -283,6 +346,7 @@ def build_runtime_decoder_graph(
 
     # -- decoder stack
     kv_state: list[tuple[str | None, str]] = []
+    expert_tiles: list[str] = []
     cache_shape = (
         (kv_blocks + 1, hkv, kv_block_size, p) if paged else (hkv, cap, p)
     )
@@ -344,13 +408,11 @@ def build_runtime_decoder_graph(
         g.add_node("Add", [x, o], [x2], dims=(s, e), scales=sc_res)
 
         h2 = add_norm(x2, pre + "norm2", pre + "ln2", s)
-        if cfg.mlp == "swiglu":
-            gt = add_linear(h2, pre + "gate", (s, e, f), pre + "gate_out")
-            up = add_linear(h2, pre + "up", (s, e, f), pre + "up_out")
-            sm = g.add_tensor(pre + "silu", (s, f))
-            g.add_node("SiluMul", [gt, up], [sm], dims=(s, f),
-                       scales=(s_act, s_act, s_act))
-            dn = add_linear(sm, pre + "down", (s, f, e), pre + "down_out")
+        if cfg.n_experts:
+            dn, tiles = add_expert_layer(h2, pre)
+            expert_tiles.append(tiles)
+        elif cfg.mlp == "swiglu":
+            dn = add_swiglu(h2, pre, f)
         else:  # gelu MLP: activation fused into the up-projection epilogue
             up = add_linear(h2, pre + "up", (s, e, f), pre + "up_out", bias=True,
                             activation="gelu", s_preact=s_act)
@@ -373,6 +435,11 @@ def build_runtime_decoder_graph(
                scale=s_act * s_w, tied=tied)
     g.outputs.append(out)
     g.outputs += [cout for _, cout in kv_state]
+    if expert_tiles:
+        # pairs routed and experts with rows, summed over the layers
+        counts = g.add_tensor(EXPERT_COUNTS, (2,), dtype="int32")
+        g.add_node("MoeCount", expert_tiles, [counts], dims=(2,))
+        g.outputs.append(counts)
     return g.validate(), kv_state
 
 
@@ -535,6 +602,12 @@ def lower_decoder(
             "paged lowering needs both kv_block_size and kv_blocks "
             f"(got kv_block_size={kv_block_size}, kv_blocks={kv_blocks})"
         )
+    if cfg.n_experts and not kv_blocks:
+        raise ValueError(
+            f"{cfg.name}: an expert layer lowers over the paged KV region "
+            "(kv_block_size/kv_blocks): its lane masks and counters ride on "
+            "the paged chunk and decode steps"
+        )
     quant = {"s_act": s_act, "s_res": s_res, "s_w": s_w}
 
     def one(phase: str) -> DeploymentPlan:
@@ -582,13 +655,13 @@ def lower(
     """Compile one config into its executable deployment artifact.
 
     Encoder family: a single forward :class:`DeploymentPlan`.  Decoder
-    (dense) family: a :class:`DecoderPlanPair` — prefill + decode-step
+    families (dense, moe): a :class:`DecoderPlanPair` — prefill + decode-step
     schedules linked through a shared static KV-cache region
     (``max_len`` tokens of capacity), dense per-slot strips by default or
     a shared paged block pool when ``kv_block_size``/``kv_blocks`` are
     set.
     """
-    if is_dense_decoder(cfg):
+    if is_decoder_lm(cfg):
         if head_by_head or not include_head:
             raise NotImplementedError(
                 "head_by_head/include_head are encoder-only options; the "
@@ -611,10 +684,7 @@ def lower(
             "lower unfused"
         )
     if cfg.family != "encoder":
-        detail = ""
-        if cfg.family == "dense":  # dense shell around an expert MLP
-            detail = f"dense config with n_experts={cfg.n_experts} routes as MoE"
-        raise UnsupportedFamilyError(cfg, detail)
+        raise UnsupportedFamilyError(cfg)
     g = build_runtime_encoder_graph(
         cfg, seq_len, s_act=s_act, s_res=s_res, s_w=s_w, include_head=include_head
     )

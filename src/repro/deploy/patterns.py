@@ -41,6 +41,15 @@ KIND_BY_OP = {
     "SiluMul": "silumul",
     "LastTok": "lasttok",
     "LMHead": "lmhead",
+    # the expert layer (repro.models.moe): one kind, so every node runs
+    # under a ``moe.<node>`` scope; the executor dispatches on the op
+    "MoeRouter": "moe",
+    "MoeRoute": "moe",
+    "MoeDispatch": "moe",
+    "ExpertGemm": "moe",
+    "MoeCombine": "moe",
+    "SharedGate": "moe",
+    "MoeCount": "moe",
 }
 
 
@@ -48,7 +57,8 @@ def _ceil_to(d: int, g: int) -> int:
     return math.ceil(d / g) * g
 
 
-def opdesc_from_attrs(kind: str, attrs: dict, granule: int = ITA_GRANULE) -> OpDesc:
+def opdesc_from_attrs(kind: str, attrs: dict, granule: int = ITA_GRANULE,
+                      op: str = "") -> OpDesc:
     """Shape/type description the support predicate sees for one operator.
 
     The ONE re-derivation of the engine-mapping input: both the lowering
@@ -67,8 +77,15 @@ def opdesc_from_attrs(kind: str, attrs: dict, granule: int = ITA_GRANULE) -> OpD
     to the M=64 vector length would occupy the accelerator at <2%
     utilization, so Deeploy's bottom-up rule sends them to the cluster —
     the predicate must see the degenerate shape to decide that.
+
+    An expert layer's grouped GEMM (op ``ExpertGemm``) reports its
+    contracting and output dims only: its rows are each expert's group,
+    padded to the row tile by the dispatch.
     """
     dims = tuple(attrs.get("dims", ()))
+    if op == "ExpertGemm":
+        _, k, nn = dims
+        return OpDesc("expert_gemm", shapes=((k, nn),))
     if kind == "gemm":
         m, k, nn = dims
         mm = _ceil_to(m, granule) if attrs.get("pad_m", True) else m
@@ -86,7 +103,7 @@ def opdesc_from_attrs(kind: str, attrs: dict, granule: int = ITA_GRANULE) -> OpD
 
 def node_opdesc(n: Node, granule: int = ITA_GRANULE) -> OpDesc:
     """:func:`opdesc_from_attrs` for a graph :class:`Node` (pre-lowering)."""
-    return opdesc_from_attrs(KIND_BY_OP.get(n.op, n.op.lower()), n.attrs, granule)
+    return opdesc_from_attrs(KIND_BY_OP.get(n.op, n.op.lower()), n.attrs, granule, n.op)
 
 
 def plan_node_opdesc(n, granule: int = ITA_GRANULE) -> OpDesc:
@@ -96,7 +113,7 @@ def plan_node_opdesc(n, granule: int = ITA_GRANULE) -> OpDesc:
     actually resolve — so the verifier audits the artifact as it will
     run, not as it was meant to be lowered.
     """
-    return opdesc_from_attrs(n.kind, n.attrs, granule)
+    return opdesc_from_attrs(n.kind, n.attrs, granule, n.op)
 
 
 def fuse_mha(g: Graph) -> Graph:

@@ -43,6 +43,8 @@ pinned block's rows are immutable while indexed.
 from __future__ import annotations
 
 import dataclasses
+import heapq
+import itertools
 
 import numpy as np
 
@@ -202,12 +204,15 @@ class PrefixIndex:
 
     # -- reclaim -----------------------------------------------------------
 
-    def _walk(self, node=None, depth=0):
-        """Yield ``(node, depth)`` over the whole trie (root included)."""
-        node = self._root if node is None else node
-        yield node, depth
-        for child in node.children.values():
-            yield from self._walk(child, depth + 1)
+    def _walk(self):
+        """Yield ``(node, depth)`` over the whole trie in pre-order (root
+        included).  Iterative: a chain is as deep as its prompt has blocks,
+        and a nested generator would pay that depth on every node."""
+        stack = [(self._root, 0)]
+        while stack:
+            node, depth = stack.pop()
+            yield node, depth
+            stack.extend((child, depth + 1) for child in reversed(node.children.values()))
 
     def reclaimable(self) -> int:
         """Blocks a full :meth:`reclaim` could return to the pool *now*:
@@ -246,46 +251,45 @@ class PrefixIndex:
         be attached depends on).  Returns the number of blocks actually
         returned to the pool.
         """
+        if need is not None and need <= 0:
+            return 0
         guard = {int(b) for b in protect}
+        # One walk numbers every entry in visiting order (a node's
+        # terminals, then its child entries); (tick, number) orders the
+        # victims.  A heap holds the entries removable now; a node joins it
+        # once its last terminal and child are gone.
+        heap, entry_of, number = [], {}, itertools.count()
+
+        def offer_node(child: _Node) -> None:
+            parent, key, n = entry_of[id(child)]
+            if (not child.children and not child.terminals
+                    and self._alloc.refcount(child.block) == 1
+                    and child.block not in guard):
+                heapq.heappush(heap, (child.tick, n, parent, key, None))
+
+        for node, _ in self._walk():
+            for tail, term in node.terminals.items():
+                n = next(number)
+                if term.block is None or (self._alloc.refcount(term.block) == 1
+                                          and term.block not in guard):
+                    heapq.heappush(heap, (term.tick, n, node, tail, term))
+            for key, child in node.children.items():
+                entry_of[id(child)] = (node, key, next(number))
+                offer_node(child)
         freed = 0
-        while need is None or freed < need:
-            victim = None  # (tick, seq, kind, remove_fn, frees_block)
-            seq = 0
-            for node, _ in self._walk():
-                for tail, term in list(node.terminals.items()):
-                    seq += 1
-                    ok = term.block is None or (
-                        self._alloc.refcount(term.block) == 1
-                        and term.block not in guard)
-                    if ok and (victim is None
-                               or (term.tick, seq) < victim[:2]):
-                        victim = (term.tick, seq, "terminal", (node, tail),
-                                  term.block is not None)
-                for key, child in node.children.items():
-                    seq += 1
-                    if child.children or child.terminals:
-                        continue  # interior: children must go first
-                    if (self._alloc.refcount(child.block) == 1
-                            and child.block not in guard
-                            and (victim is None
-                                 or (child.tick, seq) < victim[:2])):
-                        victim = (child.tick, seq, "node", (node, key), True)
-            if victim is None:
-                return freed
-            _, _, kind, where, frees = victim
-            if kind == "terminal":
-                node, tail = where
-                term = node.terminals.pop(tail)
-                if term.block is not None:
-                    self._alloc.free([term.block])
-                    self._pinned -= 1
-                    freed += 1
+        while heap and (need is None or freed < need):
+            _, _, parent, key, term = heapq.heappop(heap)
+            if term is not None:
+                del parent.terminals[key]
+                block = term.block
             else:
-                parent, key = where
-                child = parent.children.pop(key)
-                self._alloc.free([child.block])
+                block = parent.children.pop(key).block
+            if block is not None:
+                self._alloc.free([block])
                 self._pinned -= 1
                 freed += 1
+            if parent is not self._root:
+                offer_node(parent)
         return freed
 
     def drop_all(self) -> int:

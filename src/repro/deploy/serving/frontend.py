@@ -82,6 +82,9 @@ def _stats_payload(engine: AsyncEngine) -> dict:
         "prefix_hit_rate": s.prefix_hit_rate(),
         "blocks_shared": s.blocks_shared,
         "cow_copies": s.cow_copies,
+        # expert layers (zero for a plan without them)
+        "expert_rows": s.expert_rows,
+        "expert_groups": s.expert_groups,
         "scheduler": eng.scheduler_snapshot(),
         # concurrency / KV-lifetime sanitizer counters (all zero unless
         # the process runs with REPRO_SANITIZE=1); "audit_findings" are

@@ -516,7 +516,9 @@ def _check_quant(ctx: _Ctx) -> None:
                     hint="requantization folds scales into fixed-point "
                          "multipliers; this one cannot be folded",
                 )
-        if n.kind != "gemm" or bad_scale:
+        # GEMMs, and an expert layer's grouped GEMMs, requantize an int32
+        # accumulator of a K-deep int8 dot
+        if not (n.kind == "gemm" or n.op == "ExpertGemm") or bad_scale:
             continue
         scales = n.attrs.get("scales")
         dims = n.attrs.get("dims")

@@ -10,8 +10,10 @@ the oracle (interpret=True on CPU, compiled on TPU).
 - ``itamax``         : standalone rowwise integer softmax
 - ``igelu``          : standalone elementwise i-GeLU
 - ``silumul``        : SwiGLU's gated product with the integer SiLU
+- ``expert_gemm``    : an expert layer's dropless grouped int8 GEMM
 """
 
+from repro.kernels.expert_gemm import expert_gemm, expert_gemm_ref  # noqa: F401
 from repro.kernels.igelu import igelu, igelu_ref  # noqa: F401
 from repro.kernels.int8_gemm import int8_gemm, int8_gemm_ref  # noqa: F401
 from repro.kernels.ita_attention import ita_attention, ita_attention_ref  # noqa: F401

@@ -26,6 +26,20 @@ from repro.core.quant_linear import ACT_GELU, ACT_IDENTITY, ACT_RELU
 from repro.quant.qparams import requantize
 
 
+def epilogue(acc, mult, shift, *, act: int = ACT_IDENTITY, gelu: IGeluParams | None = None,
+             gelu_mult: int = 0, gelu_shift: int = 31):
+    """The int32 accumulator (bias added) -> int8: fixed-point requant and
+    the activation unit on the output path."""
+    if act == ACT_IDENTITY:
+        return requantize(acc, mult, shift)
+    if act == ACT_RELU:
+        return requantize(jnp.maximum(acc, 0), mult, shift)
+    if act == ACT_GELU:
+        pre = requantize(acc, mult, shift)
+        return requantize(igelu_int(pre, gelu), gelu_mult, gelu_shift)
+    raise ValueError(f"unknown act {act}")
+
+
 def _gemm_kernel(
     x_ref,  # (bm, bk) int8
     w_ref,  # (bk, bn) int8
@@ -54,19 +68,9 @@ def _gemm_kernel(
 
     @pl.when(k == pl.num_programs(2) - 1)
     def _epilogue():
-        acc = acc_ref[...] + bias_ref[...]
-        mult = mult_ref[...]
-        shift = shift_ref[...]
-        if act == ACT_IDENTITY:
-            o_ref[...] = requantize(acc, mult, shift)
-        elif act == ACT_RELU:
-            o_ref[...] = requantize(jnp.maximum(acc, 0), mult, shift)
-        elif act == ACT_GELU:
-            pre = requantize(acc, mult, shift)
-            raw = igelu_int(pre, gelu)
-            o_ref[...] = requantize(raw, gelu_mult, gelu_shift)
-        else:
-            raise ValueError(f"unknown act {act}")
+        o_ref[...] = epilogue(acc_ref[...] + bias_ref[...], mult_ref[...], shift_ref[...],
+                              act=act, gelu=gelu, gelu_mult=gelu_mult,
+                              gelu_shift=gelu_shift)
 
 
 @functools.partial(

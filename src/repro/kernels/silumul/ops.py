@@ -21,7 +21,9 @@ from repro.models import layers as L
 ROW_TILE = 32
 
 
-def silumul(g_q: jnp.ndarray, u_q: jnp.ndarray, *, scales) -> jnp.ndarray:
+def silumul(g_q: jnp.ndarray, u_q: jnp.ndarray, *, scales, live_rows=None) -> jnp.ndarray:
+    """``live_rows`` (int32 scalar): rows from it on are zero in the result,
+    and their blocks are neither read nor computed."""
     *lead, f = g_q.shape
     m = math.prod(lead)
     pad = -m % ROW_TILE
@@ -30,10 +32,14 @@ def silumul(g_q: jnp.ndarray, u_q: jnp.ndarray, *, scales) -> jnp.ndarray:
         # zero rows, sliced away after the kernel
         g2 = jnp.pad(g2, ((0, pad), (0, 0)))
         u2 = jnp.pad(u2, ((0, pad), (0, 0)))
+    live = None
+    if live_rows is not None:
+        live = jnp.reshape((jnp.asarray(live_rows, jnp.int32) + ROW_TILE - 1) // ROW_TILE, (1,))
     out = silumul_pallas(
         g2, u2, params=L.silumul_params(*scales),
         block_m=ROW_TILE,
         block_n=next((b for b in (512, 128) if f % b == 0), f),
+        live=live,
         interpret=interpret_mode(),
     )
     if pad:

@@ -266,7 +266,7 @@ def run_via_plan(
     cfg = get_config(arch)
     if reduced_cfg:
         cfg = reduced(cfg)
-    is_decoder = api.is_dense_decoder(cfg)
+    is_decoder = api.is_decoder_lm(cfg)
     if is_decoder and head_by_head:
         print("[note   ] --head-by-head is encoder-only; decoder pairs always "
               "emit fused attention (flag ignored)")

@@ -33,7 +33,7 @@ def compile_for_serving(cfg, args, *, extra_prompt: int = 0):
     """One ``compile()`` call for both families (the shared CLI surface)."""
     from repro.deploy import api
 
-    is_decoder = api.is_dense_decoder(cfg)
+    is_decoder = api.is_decoder_lm(cfg)
     t0 = time.time()
     model = api.compile(
         cfg,

@@ -572,8 +572,13 @@ def quantize_params(cfg: ArchConfig, params: dict, q: L.QuantConfig = L.QuantCon
                 "wo": quant_linear(lp["attn"]["wo"], q.s_act),
             },
             "norm2": quant_norm(lp["norm2"]),
-            "mlp": {k: quant_linear(v, q.s_act) for k, v in lp["mlp"].items()},
         }
+        if cfg.n_experts:
+            from repro.models import moe as moe_mod
+
+            out["mlp"] = moe_mod.quantize_moe_layer(cfg, lp["mlp"], quant_w)
+        else:
+            out["mlp"] = {k: quant_linear(v, q.s_act) for k, v in lp["mlp"].items()}
         return out
 
     qp = {

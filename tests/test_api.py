@@ -352,7 +352,7 @@ class TestPairRoundTrip:
 
 
 class TestUnsupportedFamily:
-    @pytest.mark.parametrize("arch", ["qwen2-moe-a2.7b", "llava-next-34b",
+    @pytest.mark.parametrize("arch", ["zamba2-2.7b", "llava-next-34b",
                                       "seamless-m4t-large-v2", "mamba2-370m"])
     def test_one_clear_error_naming_the_family(self, arch):
         cfg = reduced(get_config(arch))

@@ -23,6 +23,8 @@ from jax.sharding import SingleDeviceSharding
 from repro.core.attention import MhaQParams
 from repro.core.igelu import make_igelu_params
 from repro.core.quant_linear import ACT_GELU, ACT_IDENTITY
+from repro.kernels.expert_gemm.kernel import expert_gemm_pallas
+from repro.kernels.expert_gemm.ops import block_n_for
 from repro.kernels.igelu.kernel import igelu_pallas
 from repro.kernels.int8_gemm.kernel import int8_gemm_pallas
 from repro.kernels.ita_attention.kernel import ita_attention_pallas
@@ -30,6 +32,8 @@ from repro.kernels.itamax.kernel import itamax_pallas
 from repro.kernels.silumul.kernel import silumul_pallas
 from repro.kernels.silumul.ops import ROW_TILE
 from repro.models import layers as L
+from repro.models.moe import ROW_TILE as MOE_ROW_TILE
+from repro.models.moe import grouped_rows
 from repro.quant.qparams import make_qparams
 
 
@@ -133,3 +137,39 @@ def test_silumul(one_chip, m):
 
     shape = ((m, 8192), jnp.int8)
     assert "tpu_custom_call" in _compiled_text(fn, one_chip, shape, shape)
+
+
+@pytest.mark.parametrize("pairs", [4096 * 4, 32 * 4], ids=["chunk", "decode"])
+def test_silumul_expert_rows(one_chip, pairs):
+    """The gated product between Qwen1.5-MoE-A2.7B's grouped expert GEMMs
+    (F 1408), told the live row blocks as the ``ita`` runner calls it."""
+    rows = grouped_rows(pairs, 60, MOE_ROW_TILE)[1]
+    params = L.silumul_params(0.05, 0.05, 0.05)
+
+    def fn(g, u, live):
+        return silumul_pallas(g, u, params=params, block_m=ROW_TILE, block_n=128,
+                              live=live, interpret=False)
+
+    shape = ((rows, 1408), jnp.int8)
+    text = _compiled_text(fn, one_chip, shape, shape, ((1,), jnp.int32))
+    assert "tpu_custom_call" in text and "silumul_pallas" in text
+
+
+@pytest.mark.parametrize("pairs,k,n", [(4096 * 4, 2048, 1408), (32 * 4, 1408, 2048)],
+                         ids=["chunk-gate", "decode-down"])
+def test_expert_gemm(one_chip, pairs, k, n):
+    """Qwen1.5-MoE-A2.7B's grouped expert GEMMs (60 experts of 1408, top-4)
+    as the ``ita`` runner tiles them: the gate/up map of a chunk step (32
+    lanes x 128 tokens) and the down map of a decode step (32 lanes)."""
+    tiles, rows = grouped_rows(pairs, 60, MOE_ROW_TILE)
+    qp = make_qparams(0.05, 0.01, 0.05)
+
+    def fn(x, tile_e, live, w):
+        vec = jnp.full((n,), qp.mult, jnp.int32)
+        return expert_gemm_pallas(x, tile_e, live, w, vec, jnp.full((n,), qp.shift, jnp.int32),
+                                  block_m=MOE_ROW_TILE, block_n=block_n_for(k, n),
+                                  interpret=False)
+
+    text = _compiled_text(fn, one_chip, ((rows, k), jnp.int8), ((tiles,), jnp.int32),
+                          ((1,), jnp.int32), ((60, k, n), jnp.int8))
+    assert "tpu_custom_call" in text and "expert_gemm_pallas" in text

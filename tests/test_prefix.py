@@ -12,6 +12,8 @@ shared; reclaim never frees a block a live request holds); and the
 KV-sharing audit (rules KV006/KV007) is clean after any schedule.
 """
 
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -222,6 +224,28 @@ class TestPrefixIndex:
         m = idx.match(hot)
         assert not m.full  # terminal + leaf went; shared node block stayed
         assert m.rows == 4 and freed >= 1
+
+    def test_reclaim_frees_deep_chains_leaf_first_in_lru_order(self):
+        # chains deeper than Python's recursion limit: the walk is iterative
+        depth = sys.getrecursionlimit() + 50
+        alloc = BlockAllocator(3 * depth)
+        idx = PrefixIndex(alloc, BLOCK)
+        prompts = [[p * 10_000 + i for i in range(depth * BLOCK)] for p in range(3)]
+        chains = []
+        for toks in prompts:
+            chain = alloc.allocate(depth)
+            idx.insert(toks, chain, np.zeros(4))
+            alloc.free(chain)
+            chains.append(chain)
+        idx.match(prompts[0])  # prompt 1 is now the coldest
+        assert idx.reclaim(depth // 2) == depth // 2
+        # the coldest chain lost its deepest blocks first; the others are whole
+        assert idx.match(prompts[1]).rows == (depth - depth // 2) * BLOCK
+        assert idx.match(prompts[0]).full and idx.match(prompts[2]).full
+        assert set(idx.pinned_blocks()) == set(chains[0]) | set(chains[2]) | set(
+            chains[1][: depth - depth // 2])
+        assert idx.reclaim() == 2 * depth + depth - depth // 2
+        assert idx.n_blocks == 0 and alloc.n_free == alloc.n_blocks
 
     def test_reclaim_protect_guard(self):
         idx = self._index()
