@@ -127,29 +127,33 @@ class DispatchTable:
     XLA arithmetic under ``Backend.W8A8`` and the Pallas kernel under
     ``Backend.ITA`` simultaneously.  A cluster slot can be overridden the
     same way (``silumul`` runs a Pallas kernel under ``Backend.ITA``)
-    without moving the op to the accelerator.
+    without moving the op to the accelerator.  ``when`` limits an
+    override to the op shapes it accepts (``attn_paged`` runs its kernel
+    at one query row); other shapes keep the slot's runner.
     """
 
     table: dict[str, dict[Engine, Callable]] = dataclasses.field(default_factory=dict)
-    overrides: dict[tuple[str, Engine, Backend], Callable] = dataclasses.field(
-        default_factory=dict
+    overrides: dict[tuple[str, Engine, Backend], tuple[Callable, Callable | None]] = (
+        dataclasses.field(default_factory=dict)
     )
 
     def register(
-        self, kind: str, engine: Engine, fn: Callable, backend: Backend | None = None
+        self, kind: str, engine: Engine, fn: Callable, backend: Backend | None = None,
+        when: Callable[[OpDesc], bool] | None = None,
     ) -> None:
         if backend is None:
             self.table.setdefault(kind, {})[engine] = fn
         else:
             self.table.setdefault(kind, {})
-            self.overrides[(kind, engine, backend)] = fn
+            self.overrides[(kind, engine, backend)] = (fn, when)
 
     def kinds(self) -> set[str]:
         return set(self.table)
 
-    def _lookup(self, kind: str, engine: Engine, backend: Backend) -> Callable:
-        fn = self.overrides.get((kind, engine, backend))
-        if fn is None:
+    def _lookup(self, kind: str, engine: Engine, backend: Backend,
+                op: OpDesc | None = None) -> Callable:
+        fn, when = self.overrides.get((kind, engine, backend), (None, None))
+        if fn is None or (when is not None and (op is None or not when(op))):
             fn = self.table[kind][engine]
         return fn
 
@@ -160,11 +164,11 @@ class DispatchTable:
 
     def resolve(self, op: OpDesc, backend: Backend) -> tuple[Engine, Callable]:
         if backend is Backend.FLOAT:
-            return Engine.CLUSTER, self._lookup(op.kind, Engine.CLUSTER, backend)
+            return Engine.CLUSTER, self._lookup(op.kind, Engine.CLUSTER, backend, op)
         granule = backend_granule(backend)
         if ita_supports(op, granule) and self._has_accelerator(op.kind, backend):
-            return Engine.ACCELERATOR, self._lookup(op.kind, Engine.ACCELERATOR, backend)
-        return Engine.CLUSTER, self._lookup(op.kind, Engine.CLUSTER, backend)
+            return Engine.ACCELERATOR, self._lookup(op.kind, Engine.ACCELERATOR, backend, op)
+        return Engine.CLUSTER, self._lookup(op.kind, Engine.CLUSTER, backend, op)
 
 
 DEFAULT_TABLE = DispatchTable()
@@ -213,7 +217,8 @@ def populate_default_table(table: DispatchTable | None = None) -> DispatchTable:
       attn_paged:  fn(q, k_pool, v_pool, pos, block_table, *, heads,
                       kv_heads, head_dim, s_act, s_out, block_k)
                       -> int8  [B, S, H*D] (block-table gather; S = 1 for
-                      decode, S = seq_len for a prefill chunk)
+                      decode, S = seq_len for a prefill chunk; under ``ita``
+                      at S = 1 a kernel reads only the live blocks)
       cache_write_paged: fn(kv, pool, pos, block_table, active | None, *,
                       kv_heads, head_dim, block_size)
                       -> int8 pool [P+1, Hkv, block_size, D] (scatter at
@@ -247,10 +252,13 @@ def populate_default_table(table: DispatchTable | None = None) -> DispatchTable:
     from repro.kernels import igelu as igelu_pallas
     from repro.kernels import int8_gemm as int8_gemm_pallas
     from repro.kernels import ita_attention as ita_attention_pallas
+    from repro.kernels import paged_attention as paged_attention_pallas
+    from repro.kernels import paged_attention_ref
     from repro.kernels import expert_gemm as expert_gemm_pallas
     from repro.kernels import expert_gemm_ref
     from repro.kernels import silumul as silumul_pallas
     from repro.kernels import silumul_ref
+    from repro.kernels.paged_attention.ref import norm_table
     from repro.models import layers as L
     from repro.quant.qparams import make_qparams, requantize
 
@@ -449,12 +457,6 @@ def populate_default_table(table: DispatchTable | None = None) -> DispatchTable:
     # (repro.deploy.paging.SCRATCH_BLOCK) — unallocated table entries and
     # inactive dispatch lanes route there, so a batched dispatch can carry
     # parked lanes without touching any live slot's rows.
-    def _norm_table(table_q, b):
-        t = jnp.asarray(table_q, jnp.int32)
-        if t.ndim == 1:
-            t = t[None]
-        return jnp.broadcast_to(t, (b, t.shape[-1]))
-
     def _cache_write_paged(kv_m, pool, pos, table_q, active=None, *,
                            kv_heads, head_dim, block_size):
         from repro.deploy.paging import SCRATCH_BLOCK
@@ -462,7 +464,7 @@ def populate_default_table(table: DispatchTable | None = None) -> DispatchTable:
         kh = _split(kv_m, kv_heads, head_dim)  # [B, Hkv, S, D]
         b, _, s, _ = kh.shape
         pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
-        table_q = _norm_table(table_q, b)
+        table_q = norm_table(table_q, b)
         rows = pos[:, None] + jnp.arange(s, dtype=jnp.int32)[None]  # [B, S]
         phys = jnp.take_along_axis(table_q, rows // block_size, axis=1)
         if active is not None:
@@ -478,35 +480,13 @@ def populate_default_table(table: DispatchTable | None = None) -> DispatchTable:
 
     table.register("cache_write_paged", Engine.CLUSTER, _cache_write_paged)
 
-    def _attn_paged(q_m, k_pool, v_pool, pos, table_q, *, heads, kv_heads,
-                    head_dim, s_act, s_out, block_k):
-        p = MhaQParams.make_flash(s_act, s_act, s_act, s_out, max(head_dim, 1))
-        qh = _split(q_m, heads, head_dim)  # [B, H, S, D]
-        b, _, s, _ = qh.shape
-        table_q = _norm_table(table_q, b)
-        # block-table gather: the slot's logical cache is its blocks
-        # concatenated in table order [B, nb*block_size, ...]; rows past
-        # the valid prefix (scratch or stale blocks) are masked below, and
-        # fully-masked flash updates are bit-neutral, so the gathered
-        # width does not change the ints.
-        kg = k_pool[table_q].transpose(0, 2, 1, 3, 4).reshape(
-            b, kv_heads, -1, head_dim)
-        vg = v_pool[table_q].transpose(0, 2, 1, 3, 4).reshape(
-            b, kv_heads, -1, head_dim)
-        rows = kg.shape[2]
-        pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
-        # query i sits at global row pos + i and attends rows [0, pos + i]
-        # — causality at a chunk offset expressed as a per-query kv_len
-        # bound, so decode (S = 1) and chunked prefill share one runner
-        kv_len = pos[:, None] + jnp.arange(s, dtype=jnp.int32)[None] + 1
-        bk = min(block_k, rows)
-        if rows % bk:
-            bk = rows  # keep flash partitioning valid for any pool size
-        out = attention_decode_i8(qh, kg, vg, kv_len[:, None, :, None], p,
-                                  block_k=bk)
-        return _merge(out)
-
-    table.register("attn_paged", Engine.CLUSTER, _attn_paged)
+    # -- attn_paged: a cluster op on every backend; under ``ita`` a decode
+    # step (one query row per lane) runs the Pallas paged-decode kernel,
+    # which reads only each lane's live blocks, and a prefill chunk keeps
+    # the XLA runner (the whole block-table gather)
+    table.register("attn_paged", Engine.CLUSTER, paged_attention_ref)
+    table.register("attn_paged", Engine.CLUSTER, paged_attention_pallas,
+                   backend=Backend.ITA, when=lambda op: op.shapes[0][0] == 1)
 
     # -- silumul: SwiGLU's gated product, a cluster op on every backend;
     # under ``ita`` it runs as a Pallas kernel (the XLA runner reads the

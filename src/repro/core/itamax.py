@@ -222,6 +222,58 @@ def rounding_rshift_safe(x: jnp.ndarray, shift: jnp.ndarray) -> jnp.ndarray:
     return (x + bias) >> shift
 
 
+def flash_mask_logits(
+    logits_block: jnp.ndarray, mask_block: jnp.ndarray | None
+) -> jnp.ndarray:
+    """The DA stage's int32 view of a logits block, masked positions out.
+
+    Int8 logits are masked in the int8 domain (4x less select traffic than
+    int32).  Sound & bit-exact: real logits are >= -128, so a masked -128
+    can never raise the row max; masked exponentials are zeroed by
+    :func:`flash_exp`.
+    """
+    if mask_block is not None and logits_block.dtype == jnp.int8:
+        return jnp.asarray(jnp.where(mask_block, logits_block, jnp.int8(-128)), jnp.int32)
+    x = jnp.asarray(logits_block, jnp.int32)
+    if mask_block is not None:
+        x = jnp.where(mask_block, x, jnp.int32(-(1 << 20)))
+    return x
+
+
+def flash_rescale(
+    state: FlashItamaxState, block_max: jnp.ndarray
+) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """DA renormalization for a block whose row max is ``block_max``:
+    returns the new running max and the denominator and accumulator
+    carried over onto it."""
+    new_m = jnp.maximum(state.m, block_max)
+    delta_old = jnp.clip(new_m - state.m, 0, 1 << 12)
+    d_r = _renorm_factor_apply(state.d, delta_old)
+    acc_r = _renorm_factor_apply(state.acc, delta_old[..., 0:1])
+    return new_m, d_r, acc_r
+
+
+def flash_exp(
+    new_m: jnp.ndarray, x: jnp.ndarray, mask_block: jnp.ndarray | None = None
+) -> jnp.ndarray:
+    """Un-normalized U0.7 exponentials of the masked logits ``x`` against
+    the running max: int32 in [0, 127], 0 at masked positions."""
+    t = jnp.clip(new_m - x, 0, 1 << 20)
+    val = _exp2_int(t, EXP_LUT7, EXP_LUT7_BITS)
+    if mask_block is not None:
+        val = jnp.where(mask_block, val, 0)
+    return val
+
+
+def flash_guard(d_new: jnp.ndarray, acc_new: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Magnitude guard: keep d (and acc, scaled identically so the final
+    ratio is unchanged) inside int32 for arbitrarily long rows."""
+    over = d_new > RESCALE_THRESH
+    d_out = jnp.where(over, rounding_rshift_safe(d_new, RESCALE_BITS), d_new)
+    acc_out = jnp.where(over, rounding_rshift_safe(acc_new, RESCALE_BITS), acc_new)
+    return d_out, acc_out
+
+
 def flash_block_update(
     state: FlashItamaxState,
     logits_block: jnp.ndarray,  # int8/int32 [..., bk]
@@ -230,28 +282,12 @@ def flash_block_update(
 ) -> FlashItamaxState:
     """One DA + fused A@V step over a KV block (pure-jnp oracle form).
 
-    The Pallas kernel runs exactly this computation with MXU dots.
+    The Pallas kernels run exactly this computation with MXU dots; the
+    paged-decode kernel runs its parts over the block's fetched rows.
     """
-    if mask_block is not None and logits_block.dtype == jnp.int8:
-        # Mask in the int8 domain (4x less select traffic than int32).
-        # Sound & bit-exact: real logits are >= -128, so a masked -128 can
-        # never raise the row max; masked exponentials are zeroed below.
-        logits_block = jnp.where(mask_block, logits_block, jnp.int8(-128))
-        x = jnp.asarray(logits_block, jnp.int32)
-    else:
-        x = jnp.asarray(logits_block, jnp.int32)
-        if mask_block is not None:
-            x = jnp.where(mask_block, x, jnp.int32(-(1 << 20)))
-    bm = jnp.max(x, axis=-1, keepdims=True)
-    new_m = jnp.maximum(state.m, bm)
-    delta_old = jnp.clip(new_m - state.m, 0, 1 << 12)
-    d_r = _renorm_factor_apply(state.d, delta_old)
-    acc_r = _renorm_factor_apply(state.acc, delta_old[..., 0:1])
-
-    t = jnp.clip(new_m - x, 0, 1 << 20)
-    val = _exp2_int(t, EXP_LUT7, EXP_LUT7_BITS)  # [..., bk] in [0, 127]
-    if mask_block is not None:
-        val = jnp.where(mask_block, val, 0)
+    x = flash_mask_logits(logits_block, mask_block)
+    new_m, d_r, acc_r = flash_rescale(state, jnp.max(x, axis=-1, keepdims=True))
+    val = flash_exp(new_m, x, mask_block)  # [..., bk] in [0, 127]
     d_new = d_r + jnp.sum(val, axis=-1, keepdims=True)
 
     # int8 x int8 -> int32 is the product the MXU takes (the TPU kernel
@@ -265,13 +301,7 @@ def flash_block_update(
         )
     else:  # v shared across rows: [bk, out_dim]
         contrib = jnp.einsum("...k,kd->...d", val8, v, preferred_element_type=jnp.int32)
-    acc_new = acc_r + contrib
-
-    # Magnitude guard: keep d (and acc, scaled identically so the final
-    # ratio is unchanged) inside int32 for arbitrarily long rows.
-    over = d_new > RESCALE_THRESH
-    d_out = jnp.where(over, rounding_rshift_safe(d_new, RESCALE_BITS), d_new)
-    acc_out = jnp.where(over, rounding_rshift_safe(acc_new, RESCALE_BITS), acc_new)
+    d_out, acc_out = flash_guard(d_new, acc_r + contrib)
     return FlashItamaxState(m=new_m, d=d_out, acc=acc_out)
 
 

@@ -29,6 +29,8 @@ from repro.kernels.igelu.kernel import igelu_pallas
 from repro.kernels.int8_gemm.kernel import int8_gemm_pallas
 from repro.kernels.ita_attention.kernel import ita_attention_pallas
 from repro.kernels.itamax.kernel import itamax_pallas
+from repro.kernels.paged_attention.kernel import paged_attention_pallas
+from repro.kernels.paged_attention.ops import chunk_for
 from repro.kernels.silumul.kernel import silumul_pallas
 from repro.kernels.silumul.ops import ROW_TILE
 from repro.models import layers as L
@@ -173,3 +175,23 @@ def test_expert_gemm(one_chip, pairs, k, n):
     text = _compiled_text(fn, one_chip, ((rows, k), jnp.int8), ((tiles,), jnp.int32),
                           ((1,), jnp.int32), ((60, k, n), jnp.int8))
     assert "tpu_custom_call" in text and "expert_gemm_pallas" in text
+
+
+def test_paged_decode_attention(one_chip):
+    """The decode step's paged attention at olmo-1b's and Qwen1.5-MoE-A2.7B's
+    widths (the same for both): 32 lanes, 16 heads of 128, a pool of 4096
+    blocks of 16 and the scratch block, tables of 128 blocks, generated rows
+    in one key block of 2048."""
+    p = MhaQParams.make_flash(0.05, 0.05, 0.05, 0.05, 128)
+
+    def fn(q, k_pool, v_pool, table, kv_len):
+        return paged_attention_pallas(
+            q, k_pool, v_pool, table, kv_len, logit_mult=int(p.logit_mult),
+            logit_shift=int(p.logit_shift), out_mult=int(p.out_mult),
+            out_shift=int(p.out_shift), block_k=2048, chunk=chunk_for(2048, 16),
+            interpret=False)
+
+    pool = ((4097, 16, 16, 128), jnp.int8)
+    text = _compiled_text(fn, one_chip, ((32, 16, 128), jnp.int8), pool, pool,
+                          ((32, 128), jnp.int32), ((32,), jnp.int32))
+    assert "tpu_custom_call" in text and "paged_attention_pallas" in text

@@ -109,3 +109,56 @@ class TestSilumulDispatch:
         w8a8 = serve("w8a8")
         assert all(len(t) == 4 for t in w8a8)
         assert serve("ita") == w8a8
+
+
+class TestPagedAttentionDispatch:
+    """``attn_paged`` stays a cluster op on every backend; under ``ita`` a
+    decode step (one query row per lane) runs the Pallas paged-decode
+    kernel, and a prefill chunk, ``w8a8`` and ``float`` the XLA runner."""
+
+    @pytest.mark.parametrize("backend,rows", [
+        (het.Backend.ITA, 1), (het.Backend.ITA, 128), (het.Backend.W8A8, 1),
+        (het.Backend.FLOAT, 1),
+    ], ids=["ita-decode", "ita-chunk", "w8a8-decode", "float-decode"])
+    def test_runner_per_backend_and_query_rows(self, backend, rows):
+        from repro.kernels.paged_attention import paged_attention, paged_attention_ref
+
+        op = het.OpDesc("attn_paged", shapes=((rows, 16 * 128),))
+        engine, fn = het.DEFAULT_TABLE.resolve(op, backend)
+        assert engine is het.Engine.CLUSTER
+        kernel = backend is het.Backend.ITA and rows == 1
+        assert fn is (paged_attention if kernel else paged_attention_ref)
+
+    def test_ita_serves_w8a8_greedy_tokens_through_shared_prefixes(self):
+        """The tiny paged SwiGLU decoder with the prefix cache on: a repeated
+        prompt attaches its cached blocks, and its first decode append into
+        the shared partial block forks a copy; ``ita``'s decode kernel reads
+        the forked and shared blocks and gives ``w8a8``'s greedy tokens."""
+        import jax
+
+        from repro.configs import get_config, reduced
+        from repro.deploy import api
+        from repro.deploy.engine import Engine
+        from repro.models import transformer as T
+
+        cfg = reduced(get_config("olmo-1b"))
+        params = T.init_params(cfg, jax.random.PRNGKey(5))
+        key = jax.random.PRNGKey(13)
+        base = [int(t) for t in jax.random.randint(key, (19,), 0, cfg.vocab)]
+        longer = base + [int(t) for t in jax.random.randint(jax.random.fold_in(key, 1),
+                                                            (5,), 0, cfg.vocab)]
+
+        def serve(backend):
+            model = api.compile(cfg, backend=backend, seq_len=8, max_len=40, use_cache=False,
+                                kv_block_size=8, kv_blocks=24, prefix_cache=True)
+            engine = Engine(model, 2, params=params)
+            first = engine.submit(base, 6)
+            engine.run_until_idle(max_steps=200)
+            later = [engine.submit(base, 6), engine.submit(longer, 4)]
+            engine.run_until_idle(max_steps=200)
+            assert engine.stats.prefix_hits == 2 and engine.stats.cow_copies >= 1
+            return [h.tokens for h in (first, *later)]
+
+        w8a8 = serve("w8a8")
+        assert [len(t) for t in w8a8] == [6, 6, 4]
+        assert serve("ita") == w8a8
