@@ -5,8 +5,7 @@
   table1_e2e            paper Table I (E2E networks, Multi-Core vs +ITA)
   comparison_sota       paper §V-C commercial-device comparison
   roofline              §Roofline terms from the dry-run artifacts
-  decode_latency        per-step decode latency, fused mega-kernel
-                        regions vs unfused, dense vs paged KV
+  decode_latency        per-step decode latency, dense vs paged KV
   engine_throughput     request-level serving engine: continuous
                         batching vs serial on the compiled artifact
                         (``--open-loop`` adds Poisson arrivals +
@@ -51,7 +50,7 @@ def main() -> None:
 
     roofline.main([])
 
-    _section("decode_latency (fused vs unfused decode step)")
+    _section("decode_latency (dense vs paged decode step)")
     from benchmarks import decode_latency
 
     decode_latency.main(["--smoke"])

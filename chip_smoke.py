@@ -309,7 +309,7 @@ def phase_encoder_ita(sizes: Sizes) -> str:
     import jax.numpy as jnp
     import numpy as np
 
-    from repro.core.heterogeneous import PALLAS_GRANULE, OpDesc, ita_supports
+    from repro.core.heterogeneous import Backend, OpDesc, backend_granule, ita_supports
     from repro.deploy import api
     from repro.models import encoder as EN
     from repro.models import layers as L
@@ -327,7 +327,8 @@ def phase_encoder_ita(sizes: Sizes) -> str:
     # accelerator it is flash ITAMax, whose integer softmax is not the
     # rowwise one of the w8a8 model path: the model's ita branch is then
     # the reference, and the kernels are held to XLA below
-    mha_on_ita = ita_supports(OpDesc("mha", ((s, cfg.head_dim),)), PALLAS_GRANULE)
+    mha_on_ita = ita_supports(OpDesc("mha", ((s, cfg.head_dim),)),
+                              backend_granule(Backend.ITA))
     engines = {n.engine for n in plan.nodes if n.op == "MHA"}
     check(engines == {"ita" if mha_on_ita else "cluster"},
           f"the plan puts MHA on {engines}; head_dim {cfg.head_dim} says "

@@ -45,14 +45,6 @@ ITA_GRANULE = 64
 ITA_MAX_DIM = 512
 TPU_GRANULE = 128
 
-# Role-named aliases: ``Backend.ITA`` runs the *Pallas* kernels and hence
-# aligns to the TPU MXU granule, while ``Backend.W8A8`` runs the
-# paper-faithful arithmetic at the ASIC's granule.  Spelled out because the
-# raw pairing ("ITA backend -> TPU granule") reads inverted at call sites;
-# use :func:`backend_granule` instead of re-deriving the mapping by hand.
-PALLAS_GRANULE = TPU_GRANULE
-ASIC_GRANULE = ITA_GRANULE
-
 
 @dataclasses.dataclass(frozen=True)
 class OpDesc:
@@ -69,8 +61,14 @@ ACCEL_KINDS = {"gemm", "expert_gemm", "mha", "relu", "gelu", "identity"}
 
 
 def backend_granule(backend: "Backend") -> int:
-    """Alignment granule at which ``resolve`` judges ``ita_supports``."""
-    return PALLAS_GRANULE if backend is Backend.ITA else ASIC_GRANULE
+    """Alignment granule at which ``resolve`` judges ``ita_supports``.
+
+    ``Backend.ITA`` runs the *Pallas* kernels and hence aligns to the TPU
+    MXU granule, while ``Backend.W8A8`` runs the paper-faithful arithmetic
+    at the ASIC's granule.  The raw pairing ("ITA backend -> TPU granule")
+    reads inverted at call sites, so derive it here, not by hand.
+    """
+    return TPU_GRANULE if backend is Backend.ITA else ITA_GRANULE
 
 
 def as_backend(backend: "Backend | str") -> "Backend":

@@ -78,7 +78,9 @@ from repro.deploy.trace import span
 #: cost-model autotuning (``autotune`` option folds the tuned knobs —
 #: kv_block_size, fusion boundary, GEMM macro-tiles — into the
 #: fingerprint and records them in the plan's ``autotune`` payload).
-COMPILER_VERSION = 5
+#: v6: region fusion and autotuning removed (no ``fused_region`` nodes,
+#: no ``fuse``/``autotune`` options): a decoder plan is the flat schedule.
+COMPILER_VERSION = 6
 
 _PAYLOAD_FORMAT = "repro.deploy.api/compiled-model"
 
@@ -428,8 +430,6 @@ def compile(  # noqa: A001 — torch.compile precedent
     kv_blocks: int | None = None,
     head_by_head: bool = False,
     include_head: bool = True,
-    fuse: bool = True,
-    autotune: bool = False,
     prefix_cache: bool = False,
     cache_dir: str | None = None,
     use_cache: bool = True,
@@ -449,23 +449,6 @@ def compile(  # noqa: A001 — torch.compile precedent
     slots, so long-context capacity is pooled instead of reserved
     worst-case per slot, and prompts beyond ``seq_len`` prefill in
     chunks (see DEPLOY.md "Paged KV region").
-
-    ``fuse=True`` (the default; decoder family only — encoder plans
-    always lower unfused) runs the region-fusion pass: contiguous
-    same-engine schedule runs collapse into ``FusedRegion`` mega-nodes
-    the executor dispatches as single jitted closures — bit-exact vs the
-    unfused plans (tested both backends, dense and paged).  Pass
-    ``fuse=False`` to force unfused plans (per-node dispatch, e.g. for
-    per-operator debugging/tracing).
-
-    ``autotune=True`` (decoder only) runs the cost-model-driven tuner
-    (:mod:`repro.deploy.autotune`) over the bit-neutral plan knobs —
-    ``kv_block_size`` (pool rows preserved), the fusion boundary, and
-    the GEMM macro-tiles — picks the predicted-cost argmin, records the
-    chosen knobs + predicted step cost in the plan's ``autotune``
-    payload, and folds them into the fingerprint, so autotuned plans
-    ride the same on-disk cache (the tuner is deterministic: a second
-    ``compile(autotune=True)`` re-derives identical knobs and hits).
 
     Cache semantics: the key is ``config_fingerprint(cfg, options)`` —
     the *full* config plus every resolved lowering option (backend
@@ -512,15 +495,6 @@ def compile(  # noqa: A001 — torch.compile precedent
             f"kv_block_size/kv_blocks must both be positive, got "
             f"{kv_block_size}/{kv_blocks}"
         )
-    # fusion targets the decode hot path; encoder artifacts ignore it so
-    # the fused-by-default surface stays family-agnostic
-    fuse = bool(fuse) and is_decoder
-    if autotune and not is_decoder:
-        raise ValueError(
-            "autotune enumerates decode-step knobs (kv_block_size, fusion "
-            f"boundary, decode GEMM tiles); {cfg.name} does not lower to a "
-            "decoder plan pair"
-        )
     if prefix_cache and not (is_decoder and nb):
         raise ValueError(
             "prefix_cache needs a paged decoder artifact: prefix sharing "
@@ -528,18 +502,6 @@ def compile(  # noqa: A001 — torch.compile precedent
             "kv_block_size/kv_blocks on a decoder config"
         )
     cap = (max_len or s + 1) if is_decoder else 0
-    tuned = None
-    fuse_min_nodes = 2
-    if autotune:
-        from repro.deploy.autotune import tune_decoder
-
-        tuned = tune_decoder(
-            cfg, seq_len=s, max_len=cap, granule=granule,
-            kv_block_size=bs, kv_blocks=nb, fuse=fuse,
-        )
-        bs = tuned.knobs["kv_block_size"]
-        nb = tuned.knobs["kv_blocks"]
-        fuse_min_nodes = tuned.knobs["fuse_min_nodes"]
     options = {
         "backend": be.value,
         "granule": granule,
@@ -549,13 +511,8 @@ def compile(  # noqa: A001 — torch.compile precedent
         "kv_blocks": nb,
         "head_by_head": head_by_head,
         "include_head": include_head,
-        "fuse": fuse,
         "prefix_cache": bool(prefix_cache),
     }
-    if autotune:
-        # the *resolved* knobs key the cache: same (config, options) ->
-        # same deterministic tuner outcome -> same fingerprint -> hit
-        options["autotune"] = dict(tuned.knobs)
     fingerprint = config_fingerprint(cfg, options)
     cache_dir = cache_dir or default_cache_dir()
     path = _cache_path(cache_dir, cfg, fingerprint)
@@ -578,10 +535,7 @@ def compile(  # noqa: A001 — torch.compile precedent
     artifact = lower(
         cfg, seq_len, head_by_head=head_by_head, include_head=include_head,
         max_len=max_len, kv_block_size=bs, kv_blocks=nb, granule=granule,
-        fuse=fuse, fuse_min_nodes=fuse_min_nodes,
     )
-    if tuned is not None:
-        artifact.decode.autotune = tuned.payload()
     model = CompiledModel(
         cfg, be, artifact, fingerprint, COMPILER_VERSION, options,
         cache_path=path if use_cache else None,
@@ -794,16 +748,6 @@ class InferenceSession:
     def kv_blocks(self) -> int:
         self._require("decoder", "kv_blocks")
         return self._pair.kv_blocks
-
-    @property
-    def decode_dispatch_count(self) -> int:
-        """Top-level dispatches per decode step — ``len(decode.nodes)``.
-
-        Fused plans collapse same-engine runs into single FusedRegion
-        dispatches, so this is the count the fusion pass moves.  The whole
-        step is one device program (``decode_step``) either way."""
-        self._require("decoder", "decode_dispatch_count")
-        return len(self._pair.decode.nodes)
 
     @property
     def blocks_free(self) -> int:

@@ -28,11 +28,10 @@ from __future__ import annotations
 import math
 from dataclasses import dataclass
 
+from repro.core.heterogeneous import ITA_GRANULE
 from repro.deploy.graph import Graph
 from repro.deploy.tiler import (
     GemmTiling,
-    ITA_GRANULE,
-    ITA_L1_BYTES,
     MhaTiling,
     solve_gemm_tiling,
     solve_mha_tiling,
@@ -58,11 +57,6 @@ class HwConfig:
     # of the fallback ops (LN / residual / head-accumulation)
     dispatch_cyc_per_granule: float = 2900.0
     aux_cyc_per_elem: float = 1.0
-    # decode-step plan costs (see plan_step_cost): per top-level plan-node
-    # launch (runner call + task programming) — the term region fusion
-    # collapses — and per KV-block table indirection on the paged gather
-    node_launch_cyc: float = 400.0
-    gather_cyc_per_block: float = 24.0
 
 
 HW = HwConfig()
@@ -252,109 +246,6 @@ def network_cost_cluster_only(g: Graph, hw: HwConfig = HW) -> NetworkCost:
     t = gop * 1e9 / (hw.cluster_gemm_ops_per_cyc * hw.freq_hz)
     e = t * hw.p_cluster_w
     return NetworkCost(gop=gop, t_ita_s=0.0, t_cluster_s=t, e_j=e, n_tiles=0)
-
-
-# -- decode-step plan cost ----------------------------------------------------
-#
-# ``network_cost`` prices runtime *graphs* (encoder forward / prefill, M =
-# seq_len).  The decode hot path is different: every GEMM has M = 1 (a
-# weight-streaming-bound GEMV), attention reads the whole KV extent
-# (``max_len`` rows, plus a block-table gather per KV block when paged),
-# and per-step latency is dominated by dispatch count — exactly the term
-# region fusion removes.  ``plan_step_cost`` prices a lowered
-# DeploymentPlan directly, so the autotuner can argmin over kv_block_size
-# / fusion boundaries / GEMM tilings without running anything.
-
-def plan_node_cycles(
-    n,
-    hw: HwConfig = HW,
-    *,
-    max_len: int = 0,
-    kv_block_size: int = 0,
-) -> float:
-    """Compute cycles of one decode-step plan node (launch cost excluded;
-    that is per *top-level* dispatch — see :func:`plan_step_cost`).  A
-    fused region prices as the sum of its body: fusion changes how many
-    launches a step pays, never how much arithmetic it does."""
-    if n.fused:
-        return sum(
-            plan_node_cycles(b, hw, max_len=max_len, kv_block_size=kv_block_size)
-            for b in n.body
-        )
-    a = n.attrs
-    dims = tuple(a.get("dims", ()))
-    if n.kind == "gemm":
-        m, k, nn = dims
-        heads = a.get("heads", 1)
-        if n.engine == "ita":
-            return heads * gemm_cycles(solve_gemm_tiling(m, nn, k), hw)
-        # cluster GEMV (decode M=1): compute vs int8 weight streaming
-        compute = 2.0 * m * k * nn * heads / hw.cluster_gemm_ops_per_cyc
-        stream = float(k * nn * heads) / hw.dma_bytes_per_cyc
-        return max(compute, stream)
-    if n.kind == "mha":
-        heads = a.get("heads", 1) if n.op == "MHA" else 1
-        t = solve_mha_tiling(a["seq"], a["head_dim"])
-        return heads * mha_head_cycles(t, a["d_model"], hw)
-    if n.kind == "lmhead":
-        _, e, v = dims
-        compute = 2.0 * e * v / hw.cluster_gemm_ops_per_cyc
-        stream = float(e * v) / hw.dma_bytes_per_cyc
-        return max(compute, stream)
-    if n.kind in ("attn_cached", "attn_paged"):
-        heads = a.get("heads", 1)
-        kv_heads = a.get("kv_heads", heads)
-        head_dim = a.get("head_dim", dims[-1] if dims else ITA_GRANULE)
-        rows = max(int(max_len or a.get("seq", 1)), 1)
-        # QK^T + AV against the full cached extent, K and V rows streamed
-        compute = 4.0 * rows * head_dim * heads / hw.cluster_gemm_ops_per_cyc
-        stream = 2.0 * rows * head_dim * kv_heads / hw.dma_bytes_per_cyc
-        cyc = max(compute, stream)
-        if n.kind == "attn_paged":
-            bs = max(int(kv_block_size or 0), 1)
-            cyc += math.ceil(rows / bs) * hw.gather_cyc_per_block
-        return cyc
-    if n.kind in ("cache_write", "cache_write_paged"):
-        kv_heads = a.get("kv_heads", 1)
-        head_dim = a.get("head_dim", dims[-1] if dims else ITA_GRANULE)
-        cyc = 2.0 * kv_heads * head_dim * hw.aux_cyc_per_elem  # one K + one V row
-        if n.kind == "cache_write_paged":
-            cyc += hw.gather_cyc_per_block  # block-table indirection
-        return cyc
-    elems = 1
-    for d in dims:
-        elems *= d
-    return float(elems) * hw.aux_cyc_per_elem
-
-
-@dataclass(frozen=True)
-class PlanStepCost:
-    """Predicted wall time of ONE decode step of a DeploymentPlan."""
-
-    n_dispatches: int  # top-level schedule entries (what fusion shrinks)
-    t_dispatch_s: float  # n_dispatches x node_launch_cyc
-    t_compute_s: float
-
-    @property
-    def t_s(self) -> float:
-        return self.t_dispatch_s + self.t_compute_s
-
-
-def plan_step_cost(plan, hw: HwConfig = HW) -> PlanStepCost:
-    """Price one step of a lowered plan: per-dispatch launch overhead
-    (fused regions count ONCE) plus the body compute of every node."""
-    compute = sum(
-        plan_node_cycles(
-            n, hw, max_len=plan.max_len, kv_block_size=plan.kv_block_size
-        )
-        for n in plan.nodes
-    )
-    n_disp = len(plan.nodes)
-    return PlanStepCost(
-        n_dispatches=n_disp,
-        t_dispatch_s=n_disp * hw.node_launch_cyc / hw.freq_hz,
-        t_compute_s=compute / hw.freq_hz,
-    )
 
 
 # -- roofline hardware targets ------------------------------------------------

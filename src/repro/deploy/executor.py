@@ -371,43 +371,10 @@ def _compile_moe(node: PlanNode, table, backend) -> Callable:
     raise NotImplementedError(f"no runner for expert-layer op {op!r} ({node.name})")
 
 
-def _compile_region(node: PlanNode, table, backend) -> Callable:
-    """Bind a FusedRegion: compile every body node, then close the whole
-    region into ONE jitted callable — a single dispatch executes the
-    entire same-engine run (cluster closures trace into one XLA
-    computation; ita bodies trace their Pallas kernels into one fused
-    program).  Nested under an outer jit the inner jit inlines, so
-    region plans stay trace-compatible."""
-    body = tuple((b, _compile_node(b, table, backend)) for b in node.body)
-    in_names, out_names = node.inputs, node.outputs
-
-    def region_fn(*args):
-        env = dict(zip(in_names, args))
-        for b, run in body:
-            with jax.named_scope(_scope(b)):
-                _store(env, b, run(env))
-        return tuple(env[t] for t in out_names)
-
-    jitted = jax.jit(region_fn)
-
-    def run(env):
-        args = tuple(env[t] for t in in_names)
-        if any(isinstance(x, jax.core.Tracer) for x in args):
-            # already under a caller's jit (the session wraps the whole
-            # schedule): inline the body so the region costs nothing —
-            # a nested pjit call boundary here measurably slows the
-            # decode step without buying a dispatch back
-            return region_fn(*args)
-        return jitted(*args)
-
-    return run
-
-
 def _store(env: dict, node: PlanNode, value) -> None:
-    """A node's result into ``env``: one array, or a tuple for a fused
-    region and for a node with several outputs (the expert layer's route
-    and dispatch)."""
-    if node.fused or len(node.outputs) > 1:
+    """A node's result into ``env``: one array, or a tuple for a node
+    with several outputs (the expert layer's route and dispatch)."""
+    if len(node.outputs) > 1:
         env.update(zip(node.outputs, value))
     else:
         env[node.outputs[0]] = value
@@ -419,8 +386,6 @@ def _scope(node: PlanNode) -> str:
 
 
 def _compile_node(node: PlanNode, table, backend) -> Callable:
-    if node.fused:
-        return _compile_region(node, table, backend)
     kind = node.kind
     if kind == "gemm":
         return _compile_gemm(node, table, backend)

@@ -567,8 +567,6 @@ def lower_decoder(
     max_len: int | None = None,
     kv_block_size: int = 0,
     kv_blocks: int = 0,
-    fuse: bool = False,
-    fuse_min_nodes: int = 2,
     granule: int = ITA_GRANULE,
     budget: int = tiler.ITA_L1_BYTES,
     s_act: float = _DEF_S_ACT,
@@ -588,12 +586,6 @@ def lower_decoder(
     ``kv_blocks > 0`` plans the **paged** KV region instead: shared
     block pools + per-slot block tables (see
     :func:`build_runtime_decoder_graph` and :mod:`repro.deploy.paging`).
-
-    ``fuse=True`` runs the region-fusion pass on both schedules after
-    tiling/memory planning: contiguous same-engine runs collapse into
-    ``FusedRegion`` mega-nodes (:func:`repro.deploy.patterns.fuse_regions`
-    — bit-exact vs the unfused plans, persistent KV writes stay
-    top-level).
     """
     s = seq_len or cfg.max_seq
     cap = max_len or (s + 1)
@@ -618,7 +610,7 @@ def lower_decoder(
         g = patterns.map_engines(g, granule)
         persistent = tuple(cin if cin is not None else cout for cin, cout in kv_state)
         aliases = {cout: cin for cin, cout in kv_state if cin is not None}
-        plan = _emit_plan(
+        return _emit_plan(
             cfg, g,
             seq_len=s if phase == "prefill" else 1,
             granule=granule, budget=budget, quant=quant,
@@ -626,7 +618,6 @@ def lower_decoder(
             kv_block_size=kv_block_size, kv_blocks=kv_blocks,
             persistent=persistent, aliases=aliases,
         )
-        return patterns.fuse_regions(plan, min_nodes=fuse_min_nodes) if fuse else plan
 
     return DecoderPlanPair(
         arch=cfg.name, seq_len=s, max_len=cap,
@@ -644,8 +635,6 @@ def lower(
     max_len: int | None = None,
     kv_block_size: int = 0,
     kv_blocks: int = 0,
-    fuse: bool = False,
-    fuse_min_nodes: int = 2,
     granule: int = ITA_GRANULE,
     budget: int = tiler.ITA_L1_BYTES,
     s_act: float = _DEF_S_ACT,
@@ -669,19 +658,13 @@ def lower(
             )
         return lower_decoder(
             cfg, seq_len, max_len=max_len, kv_block_size=kv_block_size,
-            kv_blocks=kv_blocks, fuse=fuse, fuse_min_nodes=fuse_min_nodes,
-            granule=granule, budget=budget,
+            kv_blocks=kv_blocks, granule=granule, budget=budget,
             s_act=s_act, s_res=s_res, s_w=s_w,
         )
     if kv_blocks or kv_block_size:
         raise ValueError(
             "kv_block_size/kv_blocks configure the decoder KV region; "
             f"{cfg.name} does not lower to a decoder plan pair"
-        )
-    if fuse:
-        raise NotImplementedError(
-            "region fusion targets the decode hot path; encoder plans "
-            "lower unfused"
         )
     if cfg.family != "encoder":
         raise UnsupportedFamilyError(cfg)

@@ -49,17 +49,7 @@ class TensorSpec:
 
 @dataclass(frozen=True)
 class PlanNode:
-    """One scheduled operator: engine-assigned, quant-parameterized.
-
-    A node with ``kind == "fused_region"`` is a *mega-node*: ``body``
-    holds the original schedule-ordered operators it subsumes, all on
-    the same engine.  The region serializes like any node but executes
-    as one dispatch (a jitted closure on the cluster, one fused trace
-    on ita) — the Deeploy-style operator fusion the decode hot path
-    needs.  ``inputs`` are every tensor the body reads that is produced
-    outside the region (weights included); ``outputs`` are the body
-    products consumed outside it.
-    """
+    """One scheduled operator: engine-assigned, quant-parameterized."""
 
     name: str
     op: str  # graph-level op (MatMul / MHA / LayerNorm / ...)
@@ -68,11 +58,6 @@ class PlanNode:
     inputs: tuple[str, ...]
     outputs: tuple[str, ...]
     attrs: dict = field(default_factory=dict)
-    body: tuple["PlanNode", ...] = ()  # fused_region interior, schedule order
-
-    @property
-    def fused(self) -> bool:
-        return self.kind == "fused_region"
 
     @staticmethod
     def from_dict(d: dict) -> "PlanNode":
@@ -84,7 +69,6 @@ class PlanNode:
             inputs=tuple(d["inputs"]),
             outputs=tuple(d["outputs"]),
             attrs=_tupleize(d.get("attrs", {})),
-            body=tuple(PlanNode.from_dict(b) for b in d.get("body", ())),
         )
 
 
@@ -127,8 +111,6 @@ class DeploymentPlan:
     # runtime inputs.
     kv_block_size: int = 0
     kv_blocks: int = 0
-    # autotuner record: chosen knobs + predicted cost (empty: not autotuned)
-    autotune: dict = field(default_factory=dict)
 
     @property
     def paged(self) -> bool:
@@ -143,17 +125,6 @@ class DeploymentPlan:
     def engine_of(self, node_name: str) -> str:
         return next(n.engine for n in self.nodes if n.name == node_name)
 
-    @property
-    def fused(self) -> bool:
-        return any(n.fused for n in self.nodes)
-
-    def flat_nodes(self) -> list[PlanNode]:
-        """Schedule-ordered operators with fused regions expanded."""
-        out: list[PlanNode] = []
-        for n in self.nodes:
-            out.extend(n.body if n.fused else (n,))
-        return out
-
     def counts(self) -> dict[str, int]:
         ita = sum(n.engine == "ita" for n in self.nodes)
         return {"nodes": len(self.nodes), "ita": ita, "cluster": len(self.nodes) - ita}
@@ -161,14 +132,9 @@ class DeploymentPlan:
     def validate(self) -> "DeploymentPlan":
         assert tuple(n.name for n in self.nodes) == self.schedule, "schedule desync"
         produced = set(self.inputs) | {t.name for t in self.tensors.values() if t.weight}
-        kv_writes = {cout for _, cout in self.kv_state}
         for n in self.nodes:
             for t in n.inputs:
                 assert t in produced, f"{n.name} consumes unscheduled tensor {t}"
-            if n.fused:
-                self._validate_region(n, kv_writes)
-            else:
-                assert not n.body, f"non-fused node {n.name} carries a body"
             produced.update(n.outputs)
         for t in self.outputs:
             assert t in produced, f"plan output {t} never produced"
@@ -197,30 +163,6 @@ class DeploymentPlan:
                 )
         return self
 
-    def _validate_region(self, n: PlanNode, kv_writes: set) -> None:
-        """Fusion invariants: non-empty single-engine body, no persistent
-        KV write hidden inside, dataflow closed over the region ports."""
-        assert n.body, f"fused region {n.name} has an empty body"
-        local = set(n.inputs)
-        for b in n.body:
-            assert not b.fused, f"nested fused region {b.name} in {n.name}"
-            assert b.engine == n.engine, (
-                f"fused region {n.name} ({n.engine}) contains {b.name} "
-                f"mapped to {b.engine}: fusion crossed an engine boundary"
-            )
-            for out in b.outputs:
-                assert out not in kv_writes, (
-                    f"fused region {n.name} hides persistent KV write {out}"
-                )
-            for t in b.inputs:
-                assert t in local, (
-                    f"region {n.name} body node {b.name} reads {t} which is "
-                    f"neither a region input nor produced earlier in the body"
-                )
-            local.update(b.outputs)
-        for t in n.outputs:
-            assert t in local, f"region output {t} never produced by the body"
-
     # -- serialization -------------------------------------------------------
 
     def to_dict(self) -> dict:
@@ -242,7 +184,6 @@ class DeploymentPlan:
             "kv_state": [list(p) for p in self.kv_state],
             "kv_block_size": self.kv_block_size,
             "kv_blocks": self.kv_blocks,
-            "autotune": self.autotune,
         }
 
     @staticmethod
@@ -265,7 +206,6 @@ class DeploymentPlan:
             kv_state=tuple((cin, cout) for cin, cout in d.get("kv_state", ())),
             kv_block_size=int(d.get("kv_block_size", 0)),
             kv_blocks=int(d.get("kv_blocks", 0)),
-            autotune=_tupleize(d.get("autotune", {})),
         )
         # validate=False loads the artifact as-is — the verifier CLI uses
         # it to audit corrupt plans with structured diagnostics instead of
@@ -315,12 +255,6 @@ class DecoderPlanPair:
     @property
     def paged(self) -> bool:
         return self.kv_blocks > 0
-
-    @property
-    def autotune(self) -> dict:
-        """The autotuner record (knobs + predicted cost) — kept on the
-        decode plan, which is what the tuner optimizes."""
-        return self.decode.autotune
 
     @property
     def kv_tensors(self) -> tuple[str, ...]:

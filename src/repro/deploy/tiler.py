@@ -15,11 +15,11 @@ import functools
 import math
 from dataclasses import dataclass
 
-ITA_GRANULE = 64
+from repro.core.heterogeneous import ITA_GRANULE
+
 ITA_MAX_TILE = 512
 ITA_L1_BYTES = 128 * 1024  # 32 banks x 4 KiB
 
-TPU_GRANULE = 128
 TPU_VMEM_BYTES = 96 * 1024 * 1024  # usable VMEM budget (of ~128 MiB)
 
 

@@ -7,24 +7,21 @@ offline too — every hazard class that would corrupt memory or silently
 compute the wrong function on an MMU-less target is statically decidable
 from the plan alone.  This module is that audit: a multi-analysis pass
 over any :class:`~repro.deploy.plan.DeploymentPlan` or
-:class:`~repro.deploy.plan.DecoderPlanPair` (fused and paged plans
-included) emitting structured :class:`PlanDiagnostic` records instead of
+:class:`~repro.deploy.plan.DecoderPlanPair` (paged plans included)
+emitting structured :class:`PlanDiagnostic` records instead of
 asserts, so a corrupt artifact names *all* of its defects at once.
 
 Four analyses:
 
 1. **Dataflow / lifetime** (``DF*``, ``MEM*``) — def-before-use over the
-   flattened schedule, dead intermediates, schedule desync, and
-   arena-overlap races: two tensors sharing bytes while both live
-   (fused-region bodies are expanded via ``flat_nodes()`` so a race
-   hidden inside a mega-node is still found).
+   schedule, dead intermediates, schedule desync, and arena-overlap
+   races: two tensors sharing bytes while both live.
 2. **Persistent-KV hazards** (``KV*``, ``PAIR*``) — WAR ordering on the
    in-place cache update (no node may read the stale ``cache_in`` after
    the write that produces ``cache_out``), in-plan alias offset
    agreement, prefill/decode pair offset agreement
-   (:func:`~repro.deploy.memory.shared_persistent_offsets`), fusion
-   legality (regions never cross :data:`~repro.deploy.patterns.FUSION_BARRIERS`,
-   never hide a KV write, never mix engines), and paged-pool hygiene
+   (:func:`~repro.deploy.memory.shared_persistent_offsets`), and
+   paged-pool hygiene
    (only :data:`~repro.deploy.paging.PAGED_KV_KINDS` may touch a block
    pool — anything else would read scratch rows or another slot's data).
 3. **Quant-range propagation** (``QNT*``) — static bounds on the int32
@@ -70,8 +67,6 @@ MEM001 error     two live tensors overlap in the static arena
 MEM002 error     allocation extends beyond the recorded ``memory_peak``
 KV001  error     KV WAR hazard: stale ``cache_in`` read after the in-place write
 KV002  error     KV alias/offset contract broken (in-plan or across the pair)
-KV003  error     illegal fused region (barrier/KV write inside, engine mix,
-                 nesting, port-closure violation)
 KV004  error     paged block pool touched by a non-paged kind
 KV005  error     paged pool geometry broken (block size / pool rows)
 KV006  error     refcount inconsistent with table + prefix-index references
@@ -94,15 +89,15 @@ from dataclasses import dataclass, field
 
 from repro.deploy.memory import shared_persistent_offsets
 from repro.deploy.paging import PAGED_KV_KINDS, pool_rows
-from repro.deploy.patterns import FUSION_BARRIERS, KIND_BY_OP, plan_node_opdesc
+from repro.deploy.patterns import KIND_BY_OP, plan_node_opdesc
 from repro.deploy.plan import DecoderPlanPair, DeploymentPlan, PlanNode
 
 _INT32_LIMIT = 1 << 31
 #: relative representation error above which a requant multiplier is
 #: considered broken: half an int8 LSB of the full-scale output.
 _MULT_REL_TOL = 1.0 / 256.0
-#: dispatch kinds the executor can bind (plus the region mega-node).
-_KNOWN_KINDS = frozenset(KIND_BY_OP.values()) | {"fused_region"}
+#: dispatch kinds the executor can bind.
+_KNOWN_KINDS = frozenset(KIND_BY_OP.values())
 
 
 @dataclass(frozen=True)
@@ -168,7 +163,6 @@ class _Ctx:
     diags: list = field(default_factory=list)
 
     def __post_init__(self):
-        self.flat: list[PlanNode] = self.plan.flat_nodes()
         self.weights = {t.name for t in self.plan.tensors.values() if t.weight}
         self.kv_in = {cin for cin, _ in self.plan.kv_state if cin is not None}
         self.kv_out = {cout for _, cout in self.plan.kv_state}
@@ -194,7 +188,7 @@ def _check_dataflow(ctx: _Ctx) -> None:
             hint="the executor walks nodes; the schedule is the audited order",
         )
     produced = set(plan.inputs) | ctx.weights
-    for n in ctx.flat:
+    for n in ctx.plan.nodes:
         for t in n.inputs:
             if t not in produced:
                 ctx.emit(
@@ -212,9 +206,9 @@ def _check_dataflow(ctx: _Ctx) -> None:
                 f"plan output {t!r} never produced by the schedule",
                 tensor=t,
             )
-    consumed = {t for n in ctx.flat for t in n.inputs}
+    consumed = {t for n in ctx.plan.nodes for t in n.inputs}
     keep = set(plan.outputs) | ctx.kv_out
-    for n in ctx.flat:
+    for n in ctx.plan.nodes:
         for t in n.outputs:
             if t not in consumed and t not in keep:
                 ctx.emit(
@@ -228,14 +222,14 @@ def _check_dataflow(ctx: _Ctx) -> None:
 
 
 def _lifetimes(ctx: _Ctx) -> dict[str, tuple[int, int]]:
-    """{tensor: (first touch, last touch)} over the *flattened* schedule.
+    """{tensor: (first touch, last touch)} over the schedule.
 
     Robust to broken schedules (a consumer before the producer widens the
     interval instead of crashing) — the verifier must keep analyzing a
     plan that already failed DF001.  Persistent KV tensors span the whole
     schedule: they must survive across plan invocations.
     """
-    last = max(len(ctx.flat) - 1, 0)
+    last = max(len(ctx.plan.nodes) - 1, 0)
     lt: dict[str, list[int]] = {}
 
     def touch(t: str, i: int) -> None:
@@ -245,7 +239,7 @@ def _lifetimes(ctx: _Ctx) -> dict[str, tuple[int, int]]:
 
     for t in ctx.plan.inputs:
         touch(t, 0)
-    for i, n in enumerate(ctx.flat):
+    for i, n in enumerate(ctx.plan.nodes):
         for t in n.inputs:
             touch(t, i)
         for t in n.outputs:
@@ -317,7 +311,7 @@ def _check_kv(ctx: _Ctx) -> None:
                      f"kv tensor {cout!r} has no TensorSpec", tensor=cout)
             continue
         writer = next(
-            (i for i, n in enumerate(ctx.flat) if cout in n.outputs), None
+            (i for i, n in enumerate(ctx.plan.nodes) if cout in n.outputs), None
         )
         if writer is None:
             ctx.emit(
@@ -352,7 +346,7 @@ def _check_kv(ctx: _Ctx) -> None:
                      "a moved alias splits the KV region",
             )
         if writer is not None:
-            for i, n in enumerate(ctx.flat):
+            for i, n in enumerate(ctx.plan.nodes):
                 if i > writer and cin in n.inputs:
                     ctx.emit(
                         "KV001", "error",
@@ -364,16 +358,6 @@ def _check_kv(ctx: _Ctx) -> None:
                              "snapshot the schedule assumed",
                     )
 
-    for n in plan.nodes:
-        if n.fused:
-            _check_region(ctx, n)
-        elif n.body:
-            ctx.emit(
-                "KV003", "error",
-                f"non-fused node carries a {len(n.body)}-node body",
-                node=n.name,
-            )
-
     if plan.paged:
         _check_paged(ctx)
     elif plan.kv_block_size:
@@ -382,59 +366,6 @@ def _check_kv(ctx: _Ctx) -> None:
             f"kv_block_size {plan.kv_block_size} without kv_blocks",
             hint="paging options come as a pair",
         )
-
-
-def _check_region(ctx: _Ctx, n: PlanNode) -> None:
-    if not n.body:
-        ctx.emit("KV003", "error", "fused region has an empty body", node=n.name)
-        return
-    local = set(n.inputs)
-    for b in n.body:
-        if b.fused:
-            ctx.emit("KV003", "error",
-                     f"nested fused region {b.name!r}", node=n.name)
-        if b.engine != n.engine:
-            ctx.emit(
-                "KV003", "error",
-                f"region on {n.engine!r} contains {b.name!r} mapped to "
-                f"{b.engine!r}",
-                node=n.name,
-                hint="fusion crossed an engine boundary: one dispatch "
-                     "cannot span two engines",
-            )
-        if b.kind in FUSION_BARRIERS:
-            ctx.emit(
-                "KV003", "error",
-                f"region swallows fusion barrier {b.name!r} ({b.kind})",
-                node=n.name,
-                hint="persistent KV writes are cross-dispatch contracts; "
-                     "they must stay top-level",
-            )
-        for out in b.outputs:
-            if out in ctx.kv_out:
-                ctx.emit(
-                    "KV003", "error",
-                    f"region hides persistent KV write {out!r} "
-                    f"(body node {b.name!r})",
-                    node=n.name, tensor=out,
-                )
-        for t in b.inputs:
-            if t not in local:
-                ctx.emit(
-                    "KV003", "error",
-                    f"body node {b.name!r} reads {t!r}: neither a region "
-                    f"input nor produced earlier in the body",
-                    node=n.name, tensor=t,
-                    hint="region ports must close over the body dataflow",
-                )
-        local.update(b.outputs)
-    for t in n.outputs:
-        if t not in local:
-            ctx.emit(
-                "KV003", "error",
-                f"region output {t!r} never produced by the body",
-                node=n.name, tensor=t,
-            )
 
 
 def _check_paged(ctx: _Ctx) -> None:
@@ -469,8 +400,8 @@ def _check_paged(ctx: _Ctx) -> None:
                 tensor=cin,
                 hint="block-table row arithmetic indexes out of the pool",
             )
-    for n in ctx.flat:
-        if n.kind in PAGED_KV_KINDS or n.kind == "fused_region":
+    for n in ctx.plan.nodes:
+        if n.kind in PAGED_KV_KINDS:
             continue
         touched = (set(n.inputs) | set(n.outputs)) & pool_names
         for t in sorted(touched):
@@ -503,7 +434,7 @@ def _scale_entries(n: PlanNode):
 def _check_quant(ctx: _Ctx) -> None:
     from repro.quant.qparams import quantize_multiplier
 
-    for n in ctx.flat:
+    for n in ctx.plan.nodes:
         bad_scale = False
         for path, v in _scale_entries(n):
             if not isinstance(v, (int, float)) or isinstance(v, bool) \
@@ -592,7 +523,7 @@ def _check_engines(ctx: _Ctx) -> None:
     from repro.core.heterogeneous import ita_supports
 
     granule = ctx.plan.granule
-    for n in ctx.flat + [m for m in ctx.plan.nodes if m.fused]:
+    for n in ctx.plan.nodes:
         if n.engine not in ("ita", "cluster"):
             ctx.emit(
                 "ENG001", "error",
@@ -610,8 +541,6 @@ def _check_engines(ctx: _Ctx) -> None:
                 hint=f"known kinds: {sorted(_KNOWN_KINDS)}",
             )
             continue
-        if n.fused:
-            continue  # region engine vs body engines is KV003's job
         try:
             expected = (
                 "ita" if ita_supports(plan_node_opdesc(n, granule), granule)

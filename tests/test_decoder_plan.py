@@ -17,7 +17,12 @@ from repro.configs import get_config, reduced
 from repro.configs.base import ArchConfig
 from repro.core import heterogeneous as het
 from repro.deploy import api
-from repro.deploy.executor import execute_decode, execute_prefill
+from repro.deploy.executor import (
+    execute_decode,
+    execute_decode_paged,
+    execute_prefill,
+    execute_prefill_paged,
+)
 from repro.deploy.lowering import lower, lower_decoder
 from repro.deploy.patterns import node_opdesc
 from repro.deploy.plan import DecoderPlanPair
@@ -110,6 +115,100 @@ class TestBitExactness:
         _assert_chain_bit_exact(cfg, pair, weights, qp, batch, het.Backend.W8A8, steps=2)
 
 
+# -- the session's jitted steps against an eager run of the same plan -------
+
+JIT_SEQ, JIT_MAX, JIT_BLOCK, JIT_BLOCKS = 8, 24, 4, 14
+
+
+def _eager_step(sess, step):
+    """The executor call behind one session step, run outside any jit."""
+    pair, be = sess._pair, sess.backend
+    if not pair.paged:
+        if step == "prefill":
+            return lambda w, b: execute_prefill(pair, w, b, backend=be)
+        return lambda w, c, t, p: execute_decode(pair, w, c, t, pos=p, backend=be)
+    fn = execute_prefill_paged if step == "prefill" else execute_decode_paged
+
+    def run(*args):
+        *args, acc = args
+        logits, pool, n = fn(pair, *args, backend=be)
+        return logits, pool, None if n is None else acc + n
+
+    return run
+
+
+def _hook(sess, step):
+    """Run every call of one session step eagerly too, before the jitted
+    step (which may consume its arguments); returns the (jitted, eager)
+    result pairs as they come."""
+    if step == "decode":
+        attr = "_decode_fn"
+    else:
+        attr = "_chunk_fn" if sess.paged else "_prefill_fn"
+    jitted, eager, calls = getattr(sess, attr), _eager_step(sess, step), []
+
+    def both(*args):
+        want = eager(*args)
+        calls.append((jitted(*args), want))
+        return calls[-1][0]
+
+    setattr(sess, attr, both)
+    return calls
+
+
+def _drive_and_compare(sess, cfg, step):
+    """Prefill both slots (paged: then a second chunk of slot 0, slot 1
+    parked on the scratch table), then two decode steps (paged: the
+    second with slot 1 inactive); the hooked step's jitted and eager
+    results must agree leaf for leaf."""
+    toks = jax.random.randint(jax.random.PRNGKey(3), (2, JIT_SEQ), 0, cfg.vocab,
+                              jnp.int32)
+    calls = _hook(sess, step) if step == "prefill" else None
+    logits = sess.prefill(toks)
+    if sess.paged:
+        logits = sess.prefill_chunks({0: (toks[1:], JIT_SEQ)})
+    if step == "decode":
+        calls = _hook(sess, step)
+    for active in ([True, True], [True, False]):
+        tok = jnp.argmax(logits[:, -1:, : cfg.vocab], axis=-1).astype(jnp.int32)
+        logits = sess.decode(tok, active=active if sess.paged else None)
+    assert len(calls) == (2 if sess.paged or step == "decode" else 1)
+    for got, want in calls:
+        got, want = jax.tree.leaves(got), jax.tree.leaves(want)
+        assert len(got) == len(want)
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+class TestSessionStepsMatchEager:
+    """The session jits each whole chunk or decode step; the executor run
+    eagerly on the same arguments gives the same ints: logits, KV state,
+    and an expert layer's counters."""
+
+    @pytest.mark.parametrize("step", ["prefill", "decode"],
+                             ids=["prefill-or-chunk", "decode"])
+    @pytest.mark.parametrize("region", ["dense", "paged"])
+    @pytest.mark.parametrize("backend", ["w8a8", "ita"])
+    def test_dense_decoder(self, backend, region, step):
+        cfg = reduced(get_config("olmo-1b"))
+        kw = (dict(kv_block_size=JIT_BLOCK, kv_blocks=JIT_BLOCKS)
+              if region == "paged" else {})
+        model = api.compile(cfg, backend=backend, seq_len=JIT_SEQ, max_len=JIT_MAX,
+                            use_cache=False, **kw)
+        _drive_and_compare(model.session(2, key=jax.random.PRNGKey(7)), cfg, step)
+
+    @pytest.mark.parametrize("step", ["prefill", "decode"],
+                             ids=["prefill-or-chunk", "decode"])
+    @pytest.mark.parametrize("backend", ["w8a8", "ita"])
+    def test_expert_decoder(self, backend, step):
+        cfg = reduced(get_config("qwen2-moe-a2.7b"))
+        model = api.compile(cfg, backend=backend, seq_len=JIT_SEQ, max_len=JIT_MAX,
+                            kv_block_size=JIT_BLOCK, kv_blocks=JIT_BLOCKS,
+                            use_cache=False)
+        assert model.artifact.paged
+        _drive_and_compare(model.session(2, key=jax.random.PRNGKey(7)), cfg, step)
+
+
 class TestKVRegion:
     def test_shared_static_offsets(self, olmo_setup):
         """The link: every cache tensor has identical offset/size in both
@@ -168,14 +267,13 @@ class TestEnginePlacement:
         """The paper split at both phases: aligned prefill GEMMs on ITA;
         M=1 decode GEMVs (pad_m: False) on the cluster."""
         _, pair, _, _, _ = olmo_setup
-        # flat_nodes() looks through fused regions to the original schedule
-        pre_gemms = [n for n in pair.prefill.flat_nodes() if n.op == "MatMul"]
-        dec_gemms = [n for n in pair.decode.flat_nodes() if n.op == "MatMul"]
+        pre_gemms = [n for n in pair.prefill.nodes if n.op == "MatMul"]
+        dec_gemms = [n for n in pair.decode.nodes if n.op == "MatMul"]
         assert pre_gemms and all(n.engine == "ita" for n in pre_gemms)
         assert dec_gemms and all(n.engine == "cluster" for n in dec_gemms)
         # attention / rope / cache ops are cluster kernels in both phases
         for plan in (pair.prefill, pair.decode):
-            for n in plan.flat_nodes():
+            for n in plan.nodes:
                 if n.op in ("Rope", "AttnPrefill", "AttnDecode", "CacheWrite",
                             "SiluMul", "LastTok", "LMHead"):
                     assert n.engine == "cluster", (n.name, n.engine)
@@ -197,9 +295,9 @@ class TestEnginePlacement:
 
     def test_backend_granule_aliases(self):
         """ITA backend == Pallas kernels == TPU granule; W8A8 == ASIC."""
-        assert het.backend_granule(het.Backend.ITA) == het.PALLAS_GRANULE == het.TPU_GRANULE
-        assert het.backend_granule(het.Backend.W8A8) == het.ASIC_GRANULE == het.ITA_GRANULE
-        assert het.backend_granule(het.Backend.FLOAT) == het.ASIC_GRANULE
+        assert het.backend_granule(het.Backend.ITA) == het.TPU_GRANULE == 128
+        assert het.backend_granule(het.Backend.W8A8) == het.ITA_GRANULE == 64
+        assert het.backend_granule(het.Backend.FLOAT) == het.ITA_GRANULE
 
 
 class TestModelPathParity:

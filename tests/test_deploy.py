@@ -8,6 +8,7 @@ Hypothesis property tests live in ``test_properties.py`` behind a
 import pytest
 
 from repro.configs import get_config
+from repro.core import heterogeneous as het
 from repro.deploy import costmodel, memory, patterns, tiler
 from repro.deploy.graph import build_encoder_graph
 
@@ -56,7 +57,7 @@ class TestTiler:
         t = tiler.solve_gemm_tiling(m, n, k)
         assert t.l1_bytes <= tiler.ITA_L1_BYTES
         for d in (t.tile_m, t.tile_n, t.tile_k):
-            assert d % tiler.ITA_GRANULE == 0 and d <= tiler.ITA_MAX_TILE
+            assert d % het.ITA_GRANULE == 0 and d <= tiler.ITA_MAX_TILE
 
     def test_tiles_cover_matrix(self):
         t = tiler.solve_gemm_tiling(241, 384, 384)
@@ -68,11 +69,11 @@ class TestTiler:
     def test_mha_tiling(self):
         t = tiler.solve_mha_tiling(512, 64)
         assert t.l1_bytes <= tiler.ITA_L1_BYTES
-        assert t.tile_s % tiler.ITA_GRANULE == 0
+        assert t.tile_s % het.ITA_GRANULE == 0
 
     def test_tpu_mode(self):
         t = tiler.solve_gemm_tiling(
-            4096, 8192, 8192, granule=tiler.TPU_GRANULE, budget=tiler.TPU_VMEM_BYTES
+            4096, 8192, 8192, granule=het.TPU_GRANULE, budget=tiler.TPU_VMEM_BYTES
         )
         assert t.tile_m % 128 == 0 and t.l1_bytes <= tiler.TPU_VMEM_BYTES
 
@@ -110,3 +111,12 @@ class TestCostModelAnchors:
         c = costmodel.network_cost_cluster_only(g)
         assert abs(c.gop_per_s - 0.74) < 0.01
         assert abs(c.gop_per_j - 28.5) < 1.0  # paper: 28.9 GOp/J
+
+    def test_hw_targets_single_source(self):
+        from benchmarks import roofline
+        assert roofline.PEAK_FLOPS == costmodel.TPU_V5E.peak_flops
+        assert roofline.HBM_BW == costmodel.TPU_V5E.hbm_bw
+        ita = costmodel.hw_target("ita")
+        assert ita.peak_flops == costmodel.HW.ita_ops_per_cyc * costmodel.HW.freq_hz
+        with pytest.raises(ValueError, match="unknown hw target"):
+            costmodel.hw_target("gpu")

@@ -161,7 +161,7 @@ def test_plan_pair_passes_verify_and_names_the_moe_scopes(small):
     model = _compile(acfg, "ita")
     assert model.diagnostics == () or all(d.severity == "warning" for d in model.diagnostics)
     for plan in (model.artifact.prefill, model.artifact.decode):
-        ops = {n.op: n for n in plan.flat_nodes()}
+        ops = {n.op: n for n in plan.nodes}
         for op in ("MoeRouter", "MoeRoute", "MoeDispatch", "ExpertGemm", "MoeCombine",
                    "SharedGate", "MoeCount"):
             assert ops[op].kind == "moe"
@@ -184,7 +184,7 @@ def test_saved_plan_reloads_and_the_audit_reads_the_grouped_gemm(small, tmp_path
     assert loaded.artifact.prefill.outputs == model.artifact.prefill.outputs
     plan = model.artifact.decode
     bad = [dataclasses.replace(n, attrs=dict(n.attrs, scales=(1e-6, 1e-6, 10.0)))
-           if n.op == "ExpertGemm" else n for n in plan.flat_nodes()]
+           if n.op == "ExpertGemm" else n for n in plan.nodes]
     broken = dataclasses.replace(plan, nodes=bad, schedule=tuple(n.name for n in bad))
     assert any(d.rule == "QNT001" and d.severity == "error" for d in verify(broken))
 

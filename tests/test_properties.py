@@ -393,45 +393,6 @@ class TestItamaxProperties:
         assert (np.diff(a[order]) >= 0).all()
 
 
-class TestFusionProperties:
-    @given(
-        seq=st.sampled_from([4, 8]),
-        paged=st.booleans(),
-        min_nodes=st.integers(2, 12),
-        phase=st.sampled_from(["prefill", "decode"]),
-    )
-    @settings(max_examples=16, deadline=None)
-    def test_property_fusion_respects_engines_and_kv_writes(
-            self, seq, paged, min_nodes, phase):
-        """Any geometry, any fusion boundary, both schedule phases:
-        region fusion never mixes engines inside a body, never hides a
-        KV persistent-tensor write or cache-write barrier, never nests,
-        preserves the flattened schedule order exactly, and the result
-        still validates."""
-        from repro.configs import get_config, reduced
-        from repro.deploy import patterns
-        from repro.deploy.lowering import lower_decoder
-
-        cfg = reduced(get_config("olmo-1b"))
-        kw = dict(kv_block_size=4, kv_blocks=8) if paged else {}
-        pair = lower_decoder(cfg, seq, max_len=seq + 8, fuse=False, **kw)
-        plan = getattr(pair, phase)
-        fused = patterns.fuse_regions(plan, min_nodes=min_nodes)
-        fused.validate()
-        kv_writes = {cout for _, cout in plan.kv_state}
-        assert [n.name for n in fused.flat_nodes()] == \
-            [n.name for n in plan.nodes]
-        for n in fused.nodes:
-            if not n.fused:
-                continue
-            assert len(n.body) >= max(min_nodes, 2)
-            assert {b.engine for b in n.body} == {n.engine}
-            for b in n.body:
-                assert not b.fused  # no nesting
-                assert b.kind not in patterns.FUSION_BARRIERS
-                assert not (set(b.outputs) & kv_writes)
-
-
 def _plan_from_graph(g, mem):
     """Assemble a synthetic DeploymentPlan from a scheduled graph + its
     static memory layout (shared helper for the verifier properties).

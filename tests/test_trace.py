@@ -117,11 +117,7 @@ def test_encoder_forward_opens_its_span(tmp_path):
 
 
 def _scopes(plan) -> set[str]:
-    out = set()
-    for n in plan.nodes:
-        out.add(f"{n.kind}.{n.name}")
-        out.update(f"{b.kind}.{b.name}" for b in (n.body or ()))
-    return out
+    return {f"{n.kind}.{n.name}" for n in plan.nodes}
 
 
 def _paged_args(sess, step):
@@ -151,6 +147,24 @@ def test_compiled_steps_are_named_and_carry_node_scopes(olmo, step):
     path = "/" + "\n/".join(op_names)
     expect = _scopes(plan) | {"kv_pool.slice", "kv_pool.stack"}
     assert {s for s in expect if f"/{s}/" in path} == expect
+
+
+@pytest.mark.parametrize("step", ["decode_step", "chunk_step"])
+def test_every_op_sits_directly_under_a_node_or_pool_scope(olmo, step):
+    """A plan is a flat schedule: the first scope under the step program
+    is a plan node's ``<kind>.<node>`` or the pool's slice or rebuild,
+    with no level in between, and every node opens one."""
+    cfg, params = olmo
+    model = _paged(cfg)
+    sess = model.session(2, params=params)
+    fn = sess._decode_fn if step == "decode_step" else sess._chunk_fn
+    text = fn.lower(*_paged_args(sess, step)).compile().as_text()
+    plan = model.artifact.decode if step == "decode_step" else model.artifact.prefill
+    first = {o.split("/")[0]
+             for o in re.findall(rf'op_name="jit\({step}\)/([^"]*)"', text)}
+    nodes = _scopes(plan)
+    assert first - nodes == {"kv_pool.slice", "kv_pool.stack"}
+    assert nodes <= first
 
 
 def test_other_programs_are_named(olmo):

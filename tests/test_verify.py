@@ -35,20 +35,13 @@ def cfg():
 
 @pytest.fixture(scope="module")
 def dense_pair(cfg):
-    """Unfused dense pair: flat node lists make surgical edits easy."""
-    return api.compile(cfg, seq_len=8, max_len=14, fuse=False,
-                       use_cache=False).artifact
-
-
-@pytest.fixture(scope="module")
-def fused_pair(cfg):
     return api.compile(cfg, seq_len=8, max_len=14, use_cache=False).artifact
 
 
 @pytest.fixture(scope="module")
 def paged_pair(cfg):
     return api.compile(cfg, seq_len=8, max_len=14, kv_block_size=4,
-                       kv_blocks=8, fuse=False, use_cache=False).artifact
+                       kv_blocks=8, use_cache=False).artifact
 
 
 def _mutated(pair, mutate, which="decode"):
@@ -66,25 +59,19 @@ def _error_rules(artifact):
 # ---------------------------------------------------------------------------
 
 class TestShippingClean:
-    def test_dense_pairs_clean(self, dense_pair, fused_pair):
+    def test_dense_pairs_clean(self, dense_pair):
         assert verify(dense_pair) == []
-        assert verify(fused_pair) == []
 
     def test_paged_pair_clean(self, paged_pair):
         assert verify(paged_pair) == []
-
-    def test_autotuned_clean(self, cfg):
-        m = api.compile(cfg, seq_len=8, max_len=14, autotune=True,
-                        use_cache=False)
-        assert verify(m.artifact) == []
 
     def test_encoder_clean(self):
         m = api.compile(reduced(get_config("mobilebert")), seq_len=64,
                         use_cache=False)
         assert verify(m.artifact) == []
 
-    def test_check_strict_passes_shipping(self, fused_pair, paged_pair):
-        assert check(fused_pair, strict=True) == []
+    def test_check_strict_passes_shipping(self, dense_pair, paged_pair):
+        assert check(dense_pair, strict=True) == []
         assert check(paged_pair, strict=True) == []
 
 
@@ -152,30 +139,6 @@ class TestMutations:
 
         rules = _error_rules(_mutated(dense_pair, swap_cache_offsets))
         assert "KV002" in rules
-
-    def test_barrier_crossing_fusion_kv003(self, fused_pair):
-        def merge_barrier(p):
-            nodes = p["nodes"]
-            for i in range(len(nodes) - 1):
-                region, cw = nodes[i], nodes[i + 1]
-                if region["kind"] == "fused_region" and \
-                        cw["kind"] in ("cache_write", "cache_write_paged"):
-                    produced = {o for b in region["body"]
-                                for o in b["outputs"]}
-                    region["inputs"] = list(region["inputs"]) + [
-                        t for t in cw["inputs"]
-                        if t not in produced and t not in region["inputs"]
-                    ]
-                    region["body"] = list(region["body"]) + [cw]
-                    region["outputs"] = (list(region["outputs"])
-                                         + list(cw["outputs"]))
-                    del nodes[i + 1]
-                    p["schedule"] = [n["name"] for n in nodes]
-                    return
-            raise AssertionError("no region adjacent to a cache write")
-
-        rules = _error_rules(_mutated(fused_pair, merge_barrier))
-        assert "KV003" in rules
 
     def test_scale_overflow_qnt001(self, dense_pair):
         def blow_up_weight_scale(p):
@@ -257,6 +220,33 @@ class TestMutations:
         rules = _error_rules(_mutated(dense_pair, alien_kind))
         assert "ENG002" in rules
 
+    def test_v5_fused_region_node_eng002(self, dense_pair, tmp_path):
+        """A plan written before region fusion was removed may hold a
+        ``fused_region`` node with a ``body``; read from JSON, the audit
+        names it as an unknown kind instead of crashing or passing."""
+        d = dense_pair.to_dict()
+        nodes = d["decode"]["nodes"]
+        i = next(i for i in range(len(nodes) - 1)
+                 if nodes[i]["engine"] == nodes[i + 1]["engine"])
+        body = nodes[i:i + 2]
+        produced = set(body[0]["outputs"])
+        region = {
+            "name": "fused0_" + body[0]["engine"], "op": "FusedRegion",
+            "kind": "fused_region", "engine": body[0]["engine"],
+            "inputs": list(dict.fromkeys(
+                t for b in body for t in b["inputs"] if t not in produced)),
+            "outputs": [o for b in body for o in b["outputs"]],
+            "attrs": {"n_body": 2}, "body": body,
+        }
+        nodes[i:i + 2] = [region]
+        d["decode"]["schedule"] = [n["name"] for n in nodes]
+        path = tmp_path / "v5.json"
+        path.write_text(json.dumps(d))
+        diags = verify(load_artifact(str(path)))
+        assert [(x.rule, x.severity) for x in diags if x.node == region["name"]] \
+            == [("ENG002", "error")]
+        assert main([str(path)]) == 1
+
 
 # ---------------------------------------------------------------------------
 # severities, check(), compile()/load() wiring
@@ -316,7 +306,7 @@ class TestSeveritiesAndWiring:
         asserts cannot see — an engine flip) must fail the re-verifying
         cache-hit path, not execute on the wrong engine."""
         cache = str(tmp_path / "plans")
-        kw = dict(seq_len=8, max_len=14, fuse=False, cache_dir=cache)
+        kw = dict(seq_len=8, max_len=14, cache_dir=cache)
         m = api.compile(cfg, **kw)
         assert not m.cache_hit and m.cache_path
         payload = json.loads(open(m.cache_path).read())
@@ -333,8 +323,7 @@ class TestSeveritiesAndWiring:
         assert m2.cache_hit
 
     def test_model_load_reverifies(self, cfg, tmp_path, dense_pair):
-        m = api.compile(cfg, seq_len=8, max_len=14, fuse=False,
-                        use_cache=False)
+        m = api.compile(cfg, seq_len=8, max_len=14, use_cache=False)
         path = str(tmp_path / "model.json")
         m.save(path)
         loaded = api.CompiledModel.load(path, cfg)
@@ -356,10 +345,10 @@ class TestSeveritiesAndWiring:
 # ---------------------------------------------------------------------------
 
 class TestCli:
-    def test_clean_artifacts_pass(self, fused_pair, paged_pair, tmp_path):
-        a = str(tmp_path / "fused.json")
+    def test_clean_artifacts_pass(self, dense_pair, paged_pair, tmp_path):
+        a = str(tmp_path / "dense.json")
         b = str(tmp_path / "paged.json")
-        fused_pair.save(a)
+        dense_pair.save(a)
         paged_pair.save(b)
         assert main([a, b]) == 0
         assert main([a, b, "--strict"]) == 0
@@ -401,8 +390,7 @@ class TestBindingChecks:
     def test_weight_bind_lists_all_mismatches(self, cfg):
         from repro.deploy.executor import PlanBindingError, check_bindings
 
-        m = api.compile(cfg, seq_len=8, max_len=14, fuse=False,
-                        use_cache=False)
+        m = api.compile(cfg, seq_len=8, max_len=14, use_cache=False)
         weights, _ = m.bind()
         plan = m.artifact.prefill
         names = plan.weight_names[:2]
@@ -416,16 +404,14 @@ class TestBindingChecks:
         assert len(ei.value.mismatches) == 2
 
     def test_clean_weights_bind(self, cfg):
-        m = api.compile(cfg, seq_len=8, max_len=14, fuse=False,
-                        use_cache=False)
+        m = api.compile(cfg, seq_len=8, max_len=14, use_cache=False)
         weights, _ = m.bind()  # _check_bound ran inside without raising
         assert weights
 
     def test_input_bind_rejects_bad_batch(self, cfg):
         from repro.deploy.executor import PlanBindingError, execute
 
-        m = api.compile(cfg, seq_len=8, max_len=14, fuse=False,
-                        use_cache=False)
+        m = api.compile(cfg, seq_len=8, max_len=14, use_cache=False)
         weights, _ = m.bind()
         plan = m.artifact.prefill
         with pytest.raises(PlanBindingError) as ei:
